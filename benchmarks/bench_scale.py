@@ -6,8 +6,11 @@ Two tracked records, both under the ``pr7_scale`` key:
    (``shardsweep.fleet_sweep``, every replica sub-stream of every (R, λ)
    cell a lane of one ``shard_map`` dispatch) against the per-cell
    ``fleet.sweep`` path of PR 5/6, on a forced 4-CPU-device mesh
-   (``XLA_FLAGS=--xla_force_host_platform_device_count=4``, run in a
-   subprocess so the parent's single-device JAX config is untouched).
+   (``JAX_PLATFORMS=cpu`` and
+   ``XLA_FLAGS=--xla_force_host_platform_device_count=4``, run in a
+   subprocess so the parent's single-device JAX config is untouched; the
+   child never loads the TPU runtime, so it cannot contend for a chip the
+   parent holds).  It is a CPU-mesh rehearsal, and its record says so.
    The grid simulates ~1M total requests in quick mode (~10M full); the
    sharded result must be BIT-equal and the round_robin grid must clear a
    2x sweep-throughput gain.
@@ -63,7 +66,8 @@ _WORKER = textwrap.dedent("""
             ts.append(time.perf_counter() - t0)
         return min(ts), out
 
-    res = {"devices": jax.device_count(), "n_req_per_cell": n_req,
+    res = {"platform": jax.default_backend(),
+           "devices": jax.device_count(), "n_req_per_cell": n_req,
            "cells": len(R_grid) * len(lams), "total_requests": total,
            "R_grid": R_grid, "lams": lams}
     for router in ("round_robin", "least_work"):
@@ -81,9 +85,9 @@ _WORKER = textwrap.dedent("""
 
 
 def _sharded_record(quick: bool) -> dict:
-    """Run the forced-4-device sweep comparison in a fresh process."""
+    """Run the forced-4-device sweep comparison in a fresh CPU process."""
     n_req = 42_000 if quick else 420_000
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         " --xla_force_host_platform_device_count=4").strip()
     env["PYTHONPATH"] = os.pathsep.join(
@@ -194,6 +198,7 @@ def main(quick: bool = False):
                     f"{sharded['n_req_per_cell']} reqs/cell "
                     f"({sharded['total_requests']} total), elastic b8, "
                     f"forced {sharded['devices']}-device CPU mesh",
+        "platform": sharded["platform"],
         "devices": sharded["devices"],
         "total_requests": sharded["total_requests"],
         "round_robin": sharded["round_robin"],

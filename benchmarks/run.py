@@ -32,6 +32,8 @@ def _retry(step, quick: bool, attempts: int = 3, backoff: float = 2.0):
 
 def main() -> None:
     quick = os.environ.get("REPRO_BENCH_QUICK", "0") == "1"
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (
         bench_latency_model, bench_batch_scaling, bench_order_stats,
         bench_clipping, bench_batching_policies, bench_fixed_batching,

@@ -55,7 +55,7 @@ carrying the per-replica backlog vector (``backlog_route``), and
 ``simulate_fleet_fast`` is the fleet twin of the oracle's
 ``fleet.route_oracle``.
 
-All absolute-time arithmetic runs under ``jax.experimental.enable_x64`` —
+All absolute-time arithmetic runs under :func:`x64` —
 simulated clocks reach ~1e6 seconds where float32 ULP (~0.25 s) would swamp
 the waits being measured.  Scans run with ``unroll=8``, which amortizes
 XLA's per-iteration loop overhead on CPU while keeping compile time
@@ -91,6 +91,11 @@ _NEG = -1e30
 _NO_CAP = 1e18       # "b_max=None" as a finite cap (inf would poison carries)
 
 KERNELS: Dict[str, Callable] = {}
+
+
+def x64():
+    """Scope in which the simulators' float64 clocks stay float64."""
+    return jax.enable_x64(True)
 
 
 def kernel(name: str):
@@ -267,7 +272,7 @@ def _mg1_kernel(policy, lam, dist, lat, num_requests, seed,
         else np.asarray(wl.inter, np.float64)
     service = _pad_pow2_1d(service, 0.0) if workload is not None \
         else service
-    with jax.experimental.enable_x64():
+    with x64():
         waits, lost = _impatience_scan()(
             jnp.asarray(inter, jnp.float64),
             jnp.asarray(service, jnp.float64),
@@ -359,7 +364,7 @@ def _batch_scan_kernel(policy, lam, dist, lat, num_requests, seed,
         else wl.arrivals
     tok_p = _pad_pow2_1d(wl.tokens, 0.0) if workload is not None \
         else wl.tokens
-    with jax.experimental.enable_x64():
+    with x64():
         starts, closed = _batching_scan(False)(
             jnp.asarray(arr_p, jnp.float64),
             jnp.asarray(tok_p, jnp.float64),
@@ -547,7 +552,7 @@ def _multibin_kernel(policy, lam, dist, lat, num_requests, seed,
     # output buffers padded to a power of two: one compile serves every
     # nearby workload size (fleet replica sub-streams)
     M = max(1 << max(n - 1, 1).bit_length(), 2)
-    with jax.experimental.enable_x64():
+    with x64():
         nb, o_bin, o_lo, o_hi, o_start = _multibin_loop(B, L, K, M)(
             jnp.asarray(arr_b, jnp.float64), jnp.asarray(table, jnp.float64),
             jnp.asarray(lens, jnp.int32),
@@ -623,7 +628,7 @@ def _wait_kernel(policy, lam, dist, lat, num_requests, seed,
     arr_p, _, L = _pow2_rows([arr], np.inf)
     tok_p, _, _ = _pow2_rows([tok], -np.inf)
     table = _sparse_max_table(tok_p)
-    with jax.experimental.enable_x64():
+    with x64():
         nb, o_lo, o_hi, o_start = _wait_loop(L, table.shape[0], L)(
             jnp.asarray(arr_p[0], jnp.float64),
             jnp.asarray(table, jnp.float64), jnp.int32(n),
@@ -766,7 +771,7 @@ def _srpt_kernel(policy, lam, dist, lat, num_requests, seed,
     n = len(arr)
     order, tree, tok_rank, L = _srpt_rank_arrays(arr, tok,
                                                  wl.predicted_or_true)
-    with jax.experimental.enable_x64():
+    with x64():
         starts_rank, nb = _srpt_loop(L)(
             jnp.asarray(tree, jnp.float64),
             jnp.asarray(tok_rank, jnp.float64), jnp.int32(n),
@@ -884,7 +889,7 @@ def _tandem_dynamic_kernel(policy, lam, dist, lat, num_requests, seed,
     fp_cum[0] = 0.0
     fp_cum[1:n + 1] = np.cumsum(fp)
     M = max(1 << max(n - 1, 1).bit_length(), 2)
-    with jax.experimental.enable_x64():
+    with x64():
         nb, blocked, blocked_t, deferred, o_start, o_end, o_dend = \
             _tandem_loop(L, table.shape[0], M)(
                 jnp.asarray(arr_p[0], jnp.float64),
@@ -962,7 +967,7 @@ def sweep(policies: dict, lam_grid, dist, lat,
         bmax = np.array([float(bm) if bm is not None else _NO_CAP
                          for _, _, _, bm in lanes])
         scan = _batching_scan(True) if lane_scan is None else lane_scan
-        with jax.experimental.enable_x64():
+        with x64():
             starts, closed = scan(
                 jnp.asarray(arr_l, jnp.float64),
                 jnp.asarray(tok_l, jnp.float64),
@@ -1044,7 +1049,7 @@ def sweep_noise(policy_factory: Callable[[float], BatchPolicy], lam_grid,
                 orders.append(order)
                 arrs.append(wl.arrivals)
         loop = _srpt_loop_vmapped if srpt_loop is None else srpt_loop
-        with jax.experimental.enable_x64():
+        with x64():
             starts, nbs = loop(L)(
                 jnp.asarray(np.stack(trees), jnp.float64),
                 jnp.asarray(np.stack(tok_ranks), jnp.float64),
@@ -1101,7 +1106,7 @@ def backlog_route(arrivals, work, R: int) -> np.ndarray:
     request); arrays padded to a power of two so fleet sweeps share
     compiles across workload sizes."""
     n = len(arrivals)
-    with jax.experimental.enable_x64():
+    with x64():
         rs = _backlog_scan(int(R))(
             jnp.asarray(_pad_pow2_1d(arrivals, np.inf), jnp.float64),
             jnp.asarray(_pad_pow2_1d(work, 0.0), jnp.float64))
@@ -1141,7 +1146,7 @@ def masked_backlog_route(arrivals, work, up, R: int) -> np.ndarray:
     m = len(_pad_pow2_1d(np.zeros(n), 0.0))
     up_pad = np.ones((m, up.shape[1]), bool)
     up_pad[:n] = up
-    with jax.experimental.enable_x64():
+    with x64():
         rs = _masked_backlog_scan(int(R))(
             jnp.asarray(_pad_pow2_1d(arrivals, np.inf), jnp.float64),
             jnp.asarray(_pad_pow2_1d(work, 0.0), jnp.float64),

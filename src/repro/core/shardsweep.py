@@ -50,7 +50,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import fastsim, fleet
@@ -86,10 +85,10 @@ def _sharded_batching_scan(mesh: Mesh):
     lane = _lane_spec(mesh)
     vmapped = jax.vmap(_batching_core,
                        in_axes=(0, 0, None, None, None, None, 0, 0))
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         vmapped, mesh=mesh,
         in_specs=(lane, lane, P(), P(), P(), P(), lane, lane),
-        out_specs=(lane, lane), check_rep=False))
+        out_specs=(lane, lane), check_vma=False))
 
 
 @functools.lru_cache(maxsize=None)
@@ -122,10 +121,10 @@ def _sharded_srpt_loop(mesh: Mesh, L: int):
     lane = _lane_spec(mesh)
     vmapped = jax.vmap(_srpt_core(L),
                        in_axes=(0, 0, None, None, None, None, None, None))
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         vmapped, mesh=mesh,
         in_specs=(lane, lane, P(), P(), P(), P(), P(), P()),
-        out_specs=(lane, lane), check_rep=False))
+        out_specs=(lane, lane), check_vma=False))
 
 
 def srpt_executor(mesh: Optional[Mesh] = None):
@@ -176,9 +175,9 @@ def _sharded_backlog_scan(mesh: Mesh):
     with ONE dispatch."""
     lane = _lane_spec(mesh)
     vmapped = jax.vmap(_backlog_core_padded, in_axes=(0, 0, 0))
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         vmapped, mesh=mesh, in_specs=(lane, lane, lane),
-        out_specs=lane, check_rep=False))
+        out_specs=lane, check_vma=False))
 
 
 def _stacked_assign(router, jobs, mesh: Mesh):
@@ -203,7 +202,7 @@ def _stacked_assign(router, jobs, mesh: Mesh):
         v0[j, :R] = 0.0
     for j in range(len(jobs), nl):       # duplicate lane 0 (inert)
         arr[j], wrk[j], v0[j] = arr[0], wrk[0], v0[0]
-    with jax.experimental.enable_x64():
+    with fastsim.x64():
         rs = _sharded_backlog_scan(mesh)(
             jnp.asarray(arr, jnp.float64), jnp.asarray(wrk, jnp.float64),
             jnp.asarray(v0, jnp.float64))
@@ -336,7 +335,7 @@ def fleet_sweep(R_grid, lam_grid, router, policy: BatchPolicy, dist, lat,
             tok_l[r, :len(wl.tokens)] = wl.tokens
         elas = np.full(nl, bool(elastic))
         bmax = np.full(nl, float(b_max) if b_max is not None else _NO_CAP)
-        with jax.experimental.enable_x64():
+        with fastsim.x64():
             s, c = scan(jnp.asarray(arr_l, jnp.float64),
                         jnp.asarray(tok_l, jnp.float64),
                         jnp.float64(lat.k1), jnp.float64(lat.k2),

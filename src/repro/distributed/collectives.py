@@ -17,11 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover
-    from jax.shard_map import shard_map
-
 
 def _quantize_rows(x):
     """Per-row symmetric int8. x: [r, c] -> (int8 [r, c], scales [r, 1])."""
@@ -51,5 +46,5 @@ def compressed_mean_rows(grads_by_device, mesh: Mesh, axis: str = "data"):
                                   tiled=True)                   # [size]
         return full.astype(jnp.float32)[None]
 
-    return shard_map(body, mesh=mesh, in_specs=P(axis),
+    return jax.shard_map(body, mesh=mesh, in_specs=P(axis),
                      out_specs=P(axis))(grads_by_device)

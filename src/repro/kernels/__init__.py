@@ -1,10 +1,12 @@
 """Shared kernel-package helpers.
 
-Every Pallas kernel wrapper in this package takes ``interpret=None`` and
-resolves it through :func:`default_interpret`, so the decision "compile on
-TPU, interpret everywhere else" lives in exactly one place.  Callers that
-need to force a mode (tests pinning interpret semantics, a TPU host
-debugging a kernel) pass an explicit bool.
+Every Pallas kernel function in this package (``kernel.py``) takes
+``interpret=None`` and resolves it through :func:`resolve_interpret` at
+the ``pallas_call`` itself, so the decision "compile on TPU, interpret
+everywhere else" lives in exactly one place and no caller, wrapper or
+direct, is interpreted on a TPU without asking for it.  Callers that need
+to force a mode (tests pinning interpret semantics, a TPU host debugging a
+kernel) pass an explicit bool.
 """
 
 from __future__ import annotations

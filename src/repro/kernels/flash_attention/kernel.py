@@ -18,11 +18,14 @@ for double buffering).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -85,10 +88,11 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
 
 def flash_attention_bh(q, k, v, *, causal: bool = True, window=None,
                        block_q: int = 128, block_kv: int = 256,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     """q: [B*Hq, S, D]; k/v: [B*Hkv, S, D] (same B ordering, Hq % Hkv == 0).
 
-    Returns [B*Hq, S, D]."""
+    Returns [B*Hq, S, D].  ``interpret=None`` resolves
+    through :func:`repro.kernels.resolve_interpret`."""
     bh, s, d = q.shape
     bhk = k.shape[0]
     group = bh // bhk
@@ -119,5 +123,5 @@ def flash_attention_bh(q, k, v, *, causal: bool = True, window=None,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

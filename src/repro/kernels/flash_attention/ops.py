@@ -8,7 +8,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import resolve_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_bh
 
 
@@ -29,5 +28,5 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
     vf = v.transpose(0, 2, 1, 3).reshape(b * hkv, s, d)
     out = flash_attention_bh(qf, kf, vf, causal=causal, window=window,
                              block_q=block_q, block_kv=block_kv,
-                             interpret=resolve_interpret(interpret))
+                             interpret=interpret)
     return out.reshape(b, hq, s, d).transpose(0, 2, 1, 3)

@@ -12,16 +12,25 @@ lengths [B] via scalar prefetch (drives the skip predicate before the DMA
 is issued). Grid: (B, Hkv, num_kv_blocks), kv innermost; flash-decoding
 online softmax in VMEM scratch; GQA handled by processing a whole q-head
 group (G = Hq/Hkv rows) per kv head — the [G, D] q tile rides VMEM easily.
+
+The caches are read through a free [B, S, Hkv*D] view: head h's K/V block
+is the (block_kv, D) tile at column block h.  The TPU compiler requires
+the last two block dims to be multiples of (8, 128) or the full array
+dims; a (block_kv, 1, D) block over [.., Hkv, D] with Hkv > 1 is neither,
+while (block_kv, D) is legal for D % 128 == 0 and block_kv % 8 == 0.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -43,8 +52,8 @@ def _kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
     @pl.when(k_start < length)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)           # [G, D]
-        k = k_ref[0, :, 0].astype(jnp.float32)        # [bkv, D]
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)              # [bkv, D]
+        v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale    # [G, bkv]
@@ -69,10 +78,11 @@ def _kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
 
 def ragged_decode_attention_kernel(q, k_cache, v_cache, lengths, *,
                                    block_kv: int = 256,
-                                   interpret: bool = True):
+                                   interpret: Optional[bool] = None):
     """q: [B, Hq, D]; caches: [B, S, Hkv, D]; lengths: [B] int32.
 
-    Returns [B, Hq, D]."""
+    Returns [B, Hq, D].  ``interpret=None`` resolves through
+    :func:`repro.kernels.resolve_interpret`."""
     b, hq, d = q.shape
     s, hkv = k_cache.shape[1], k_cache.shape[2]
     g = hq // hkv
@@ -81,6 +91,8 @@ def ragged_decode_attention_kernel(q, k_cache, v_cache, lengths, *,
     nkv = s // block_kv
     scale = 1.0 / (d ** 0.5)
     qg = q.reshape(b, hkv, g, d)
+    k_rows = k_cache.reshape(b, s, hkv * d)
+    v_rows = v_cache.reshape(b, s, hkv * d)
 
     kernel = functools.partial(_kernel, scale=scale, block_kv=block_kv,
                                num_kv_blocks=nkv)
@@ -90,10 +102,8 @@ def ragged_decode_attention_kernel(q, k_cache, v_cache, lengths, *,
         grid=(b, hkv, nkv),
         in_specs=[
             pl.BlockSpec((1, 1, g, d), lambda b, h, j, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_kv, 1, d),
-                         lambda b, h, j, lens: (b, j, h, 0)),
-            pl.BlockSpec((1, block_kv, 1, d),
-                         lambda b, h, j, lens: (b, j, h, 0)),
+            pl.BlockSpec((1, block_kv, d), lambda b, h, j, lens: (b, j, h)),
+            pl.BlockSpec((1, block_kv, d), lambda b, h, j, lens: (b, j, h)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d), lambda b, h, j, lens: (b, h, 0, 0)),
         scratch_shapes=[
@@ -106,6 +116,6 @@ def ragged_decode_attention_kernel(q, k_cache, v_cache, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        interpret=interpret,
-    )(lengths, qg, k_cache, v_cache)
+        interpret=resolve_interpret(interpret),
+    )(lengths, qg, k_rows, v_rows)
     return out.reshape(b, hq, d)

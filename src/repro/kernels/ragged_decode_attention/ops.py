@@ -7,7 +7,6 @@ from typing import Optional
 
 import jax
 
-from repro.kernels import resolve_interpret
 from repro.kernels.ragged_decode_attention.kernel import (
     ragged_decode_attention_kernel)
 
@@ -25,4 +24,4 @@ def ragged_decode_attention(q, k_cache, v_cache, lengths, *,
     interpreted elsewhere)."""
     return ragged_decode_attention_kernel(
         q, k_cache, v_cache, lengths.astype("int32"),
-        block_kv=block_kv, interpret=resolve_interpret(interpret))
+        block_kv=block_kv, interpret=interpret)

@@ -7,10 +7,13 @@ Row-block tiling: [block_rows, d_model] tiles in VMEM, fp32 accumulation.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 def _kernel(x_ref, res_ref, w_ref, y_ref, o_ref, *, eps):
@@ -24,10 +27,11 @@ def _kernel(x_ref, res_ref, w_ref, y_ref, o_ref, *, eps):
 
 
 def fused_rmsnorm_2d(x, residual, weight, *, eps: float = 1e-6,
-                     block_rows: int = 256, interpret: bool = True):
+                     block_rows: int = 256, interpret: Optional[bool] = None):
     """x, residual: [T, D]; weight: [D] (stored as w-1, gemma convention).
 
-    Returns (residual_out = x+residual, normed)."""
+    Returns (residual_out = x+residual, normed).  ``interpret=None`` resolves
+    through :func:`repro.kernels.resolve_interpret`."""
     t, d = x.shape
     block_rows = min(block_rows, t)
     assert t % block_rows == 0
@@ -49,5 +53,5 @@ def fused_rmsnorm_2d(x, residual, weight, *, eps: float = 1e-6,
             jax.ShapeDtypeStruct((t, d), x.dtype),
             jax.ShapeDtypeStruct((t, d), x.dtype),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, residual, weight)

@@ -7,7 +7,6 @@ from typing import Optional
 
 import jax
 
-from repro.kernels import resolve_interpret
 from repro.kernels.rmsnorm.kernel import fused_rmsnorm_2d
 
 
@@ -26,5 +25,5 @@ def fused_rmsnorm(x, residual, weight, *, eps: float = 1e-6,
     res, normed = fused_rmsnorm_2d(
         x.reshape(t, d), residual.reshape(t, d), weight,
         eps=eps, block_rows=max(block, 1),
-        interpret=resolve_interpret(interpret))
+        interpret=interpret)
     return res.reshape(shape), normed.reshape(shape)

@@ -53,7 +53,7 @@ def run_cell(arch: str, shape_id: str, mesh_kind: str, out_dir: str,
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     try:
         cell = build_cell(cfg, shape_id, mesh, overrides=dict(overrides or {}))
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(cell.step_fn, donate_argnums=cell.donate)
             lowered = jitted.lower(*cell.args)
             compiled = lowered.compile()

@@ -9,6 +9,16 @@ jax initialization, while smoke tests and benches must see 1 device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: the compiler propagates
+    shardings from the constraints ``ShardCtx`` places (GSPMD), which is
+    what this code base is written for.  ``jax.make_mesh`` defaults to
+    ``Explicit`` axes, under which the embedding gather of a sharded table
+    is an error."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,16 +27,16 @@ def make_production_mesh(*, multi_pod: bool = False):
     pure data parallelism across the DCN/ICI-superpod boundary."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_degraded_mesh(data: int = 8, model: int = 16):
     """Elastic-scaling target: e.g. after losing half a pod's hosts, restart
     on (8, 16) = 128 chips and restore the checkpoint (resharded)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 def make_host_mesh(devices=None):
     """Whatever devices exist (CPU smoke tests): 1xN mesh."""
     devices = devices if devices is not None else jax.devices()
-    return jax.make_mesh((1, len(devices)), ("data", "model"))
+    return auto_mesh((1, len(devices)), ("data", "model"))

@@ -9,6 +9,7 @@ NamedShardings (pjit in/out shardings).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -38,16 +39,25 @@ def _init_one(spec: Spec, key, dtype):
         return jnp.zeros(spec.shape, dtype)
     if spec.init == "ones":
         return jnp.ones(spec.shape, dtype)
-    fan_in = spec.shape[0] if spec.shape else 1
+    # the stacked layer-group dim (``stack_group``) is not an input width
+    widths = [n for n, a in zip(spec.shape, spec.axes) if a != "layers"]
+    fan_in = widths[0] if widths else 1
     std = spec.scale / np.sqrt(max(fan_in, 1))
     return (jax.random.normal(key, spec.shape, jnp.float32) * std).astype(dtype)
 
 
-def init_params(specs, rng, dtype=jnp.float32):
-    """Materialize a spec tree into arrays."""
-    leaves, treedef = jax.tree.flatten(specs, is_leaf=is_spec)
+@functools.partial(jax.jit, static_argnums=(0, 2))
+def _init_leaves(leaves, rng, dtype):
     keys = jax.random.split(rng, len(leaves))
-    arrs = [_init_one(s, k, dtype) for s, k in zip(leaves, keys)]
+    return [_init_one(s, k, dtype) for s, k in zip(leaves, keys)]
+
+
+def init_params(specs, rng, dtype=jnp.float32):
+    """Materialize a spec tree into arrays.  All leaves are drawn and cast
+    in one compiled program, so no float32 draw of a bfloat16 leaf is ever
+    held: the peak is the parameters themselves."""
+    leaves, treedef = jax.tree.flatten(specs, is_leaf=is_spec)
+    arrs = _init_leaves(tuple(leaves), rng, jnp.dtype(dtype))
     return jax.tree.unflatten(treedef, arrs)
 
 

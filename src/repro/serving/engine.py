@@ -140,7 +140,7 @@ class Engine:
         self.ctx = ctx
         if params is None:
             params = init_params(param_specs(cfg), jax.random.PRNGKey(seed),
-                                 jnp.float32)
+                                 jnp.dtype(cfg.dtype))
         self.params = params
         self._prefill_fns: Dict[Tuple[int, int], callable] = {}
         self._decode_fns: Dict[int, callable] = {}

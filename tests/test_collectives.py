@@ -48,7 +48,7 @@ mesh = jax.make_mesh((8,), ("data",))
 n, size = 8, 8 * 512
 sds = jax.ShapeDtypeStruct((n, size), jnp.float32,
                            sharding=NamedSharding(mesh, P("data")))
-with mesh:
+with jax.set_mesh(mesh):
     comp = jax.jit(lambda g: compressed_mean_rows(g, mesh, "data")) \
         .lower(sds).compile()
 cost = analyze_hlo_text(comp.as_text())

@@ -38,7 +38,8 @@ def _tree_equal(a, b):
     (2, 8, 256),      # lane-aligned
     (1, 4, 64),       # sub-lane F -> padded to 128 internally
     (3, 8, 65),       # odd F
-    (2, 16, 1024),    # 512-block path
+    (2, 16, 1024),    # several 128-lane rows in one block
+    (1, 4, 76805),    # more rows than one block -> padded to whole blocks
 ])
 def test_gather_rows_matches_indexing(g, b, f, dtype):
     src = jax.random.normal(RNG, (g, b, f), jnp.float32)

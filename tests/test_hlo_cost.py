@@ -14,12 +14,7 @@ def _compile(f, *args):
 
 
 def _xla_flops(compiled) -> float:
-    """compiled.cost_analysis() returns a dict in older jax and a list of
-    per-partition dicts in newer releases — normalize to total flops."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, dict):
-        return float(ca["flops"])
-    return float(sum(d.get("flops", 0.0) for d in ca))
+    return float(compiled.cost_analysis()["flops"])
 
 
 def test_scan_flops_match_unrolled():
