@@ -58,4 +58,5 @@ def gather_rows_kernel(src, idx, *, block_r: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((g, nb, r, lanes), src.dtype),
         interpret=resolve_interpret(interpret),
+        name="fused_compact",
     )(idx, src)

@@ -117,5 +117,6 @@ def ragged_decode_attention_kernel(q, k_cache, v_cache, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         interpret=resolve_interpret(interpret),
+        name="ragged_decode_attention",
     )(lengths, qg, k_rows, v_rows)
     return out.reshape(b, hq, d)

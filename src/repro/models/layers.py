@@ -283,55 +283,58 @@ def attention_block(p, x, cfg: ModelConfig, ctx: ShardCtx, *,
                     else ("batch", "kv_seq", "kv_heads", "head_dim"))
         span = k_cache.shape[2] if hm else k_cache.shape[1]
         if x.shape[1] == 1:
-            # ring-buffer slot when a sliding window bounds the cache span
-            slot = kv_lens % span
-            mode = cfg.decode_cache_update
-            k_new = k.transpose(0, 2, 1, 3) if hm else k    # [B,H,1,D] | [B,1,H,D]
-            v_new = v.transpose(0, 2, 1, 3) if hm else v
-            if mode == "uniform":
-                # static-bucket serving: every slot is at the same position
-                pos = slot[0]
-                start = (0, 0, pos, 0) if hm else (0, pos, 0, 0)
-                k_cache = lax.dynamic_update_slice(
-                    k_cache, k_new.astype(k_cache.dtype), start)
-                v_cache = lax.dynamic_update_slice(
-                    v_cache, v_new.astype(v_cache.dtype), start)
-            elif mode == "scatter":
-                bidx = jnp.arange(k.shape[0])
-                if hm:
-                    k_cache = k_cache.at[bidx, :, slot].set(
-                        k_new[:, :, 0].astype(k_cache.dtype))
-                    v_cache = v_cache.at[bidx, :, slot].set(
-                        v_new[:, :, 0].astype(v_cache.dtype))
+            # the cache write, its layout constraints and the attention read
+            # are the decode step's KV traffic: one scope for the trace
+            with jax.named_scope("kv_cache"):
+                # ring-buffer slot when a sliding window bounds the cache span
+                slot = kv_lens % span
+                mode = cfg.decode_cache_update
+                k_new = k.transpose(0, 2, 1, 3) if hm else k    # [B,H,1,D] | [B,1,H,D]
+                v_new = v.transpose(0, 2, 1, 3) if hm else v
+                if mode == "uniform":
+                    # static-bucket serving: every slot is at the same position
+                    pos = slot[0]
+                    start = (0, 0, pos, 0) if hm else (0, pos, 0, 0)
+                    k_cache = lax.dynamic_update_slice(
+                        k_cache, k_new.astype(k_cache.dtype), start)
+                    v_cache = lax.dynamic_update_slice(
+                        v_cache, v_new.astype(v_cache.dtype), start)
+                elif mode == "scatter":
+                    bidx = jnp.arange(k.shape[0])
+                    if hm:
+                        k_cache = k_cache.at[bidx, :, slot].set(
+                            k_new[:, :, 0].astype(k_cache.dtype))
+                        v_cache = v_cache.at[bidx, :, slot].set(
+                            v_new[:, :, 0].astype(v_cache.dtype))
+                    else:
+                        k_cache = k_cache.at[bidx, slot].set(
+                            k[:, 0].astype(k_cache.dtype))
+                        v_cache = v_cache.at[bidx, slot].set(
+                            v[:, 0].astype(v_cache.dtype))
+                else:  # onehot (baseline): full-cache read-modify-write
+                    oh = (jnp.arange(span)[None, :] ==
+                          slot[:, None]).astype(k_cache.dtype)
+                    oh = oh[:, None, :, None] if hm else oh[:, :, None, None]
+                    k_cache = k_cache * (1 - oh) + oh * k_new.astype(k_cache.dtype)
+                    v_cache = v_cache * (1 - oh) + oh * v_new.astype(v_cache.dtype)
+                k_cache = ctx.c(k_cache, *cache_ax)
+                v_cache = ctx.c(v_cache, *cache_ax)
+                valid = jnp.minimum(kv_lens + 1, span)
+                # ring buffer holds the most recent `valid` tokens; absolute RoPE
+                # was applied before caching so slot order is irrelevant.
+                if cfg.resolved_decode_attention_impl == "ragged" and not hm:
+                    # per-request early exit over KV blocks (elastic batching at
+                    # the kernel level): a short request only pays its own span;
+                    # interpret mode resolves via kernels.default_interpret
+                    from repro.kernels.ragged_decode_attention.ops import (
+                        ragged_decode_attention)
+                    out = ragged_decode_attention(
+                        q[:, 0], k_cache, v_cache, valid,
+                        block_kv=_ragged_block_kv(span))[:, None]
                 else:
-                    k_cache = k_cache.at[bidx, slot].set(
-                        k[:, 0].astype(k_cache.dtype))
-                    v_cache = v_cache.at[bidx, slot].set(
-                        v[:, 0].astype(v_cache.dtype))
-            else:  # onehot (baseline): full-cache read-modify-write
-                oh = (jnp.arange(span)[None, :] ==
-                      slot[:, None]).astype(k_cache.dtype)
-                oh = oh[:, None, :, None] if hm else oh[:, :, None, None]
-                k_cache = k_cache * (1 - oh) + oh * k_new.astype(k_cache.dtype)
-                v_cache = v_cache * (1 - oh) + oh * v_new.astype(v_cache.dtype)
-            k_cache = ctx.c(k_cache, *cache_ax)
-            v_cache = ctx.c(v_cache, *cache_ax)
-            valid = jnp.minimum(kv_lens + 1, span)
-            # ring buffer holds the most recent `valid` tokens; absolute RoPE
-            # was applied before caching so slot order is irrelevant.
-            if cfg.resolved_decode_attention_impl == "ragged" and not hm:
-                # per-request early exit over KV blocks (elastic batching at
-                # the kernel level): a short request only pays its own span;
-                # interpret mode resolves via kernels.default_interpret
-                from repro.kernels.ragged_decode_attention.ops import (
-                    ragged_decode_attention)
-                out = ragged_decode_attention(
-                    q[:, 0], k_cache, v_cache, valid,
-                    block_kv=_ragged_block_kv(span))[:, None]
-            else:
-                out = decode_attention(q, k_cache, v_cache, valid,
-                                       window=None, ctx=ctx,
-                                       layout=cfg.cache_layout)
+                    out = decode_attention(q, k_cache, v_cache, valid,
+                                           window=None, ctx=ctx,
+                                           layout=cfg.cache_layout)
         else:
             # prefill: attend within the prompt, then store the (windowed)
             # tail of k/v into the cache.

@@ -163,14 +163,15 @@ def _apply_position(cfg: ModelConfig, mixer: str, ffn: str, p, x, ctx,
         x = x + out
 
     if ffn != "none":
-        h2 = L.rmsnorm(x, p["ffn_norm"], cfg.norm_eps)
-        if ffn == "dense":
-            out = L.ffn_block(p["ffn"], h2, cfg, ctx)
-            if "ffn_gate" in p:
-                out = jnp.tanh(p["ffn_gate"].astype(jnp.float32)).astype(
-                    out.dtype) * out
-        else:
-            out, aux = moe_block(p["ffn"], h2, cfg, ctx, return_aux=True)
+        with jax.named_scope("ffn"):
+            h2 = L.rmsnorm(x, p["ffn_norm"], cfg.norm_eps)
+            if ffn == "dense":
+                out = L.ffn_block(p["ffn"], h2, cfg, ctx)
+                if "ffn_gate" in p:
+                    out = jnp.tanh(p["ffn_gate"].astype(jnp.float32)).astype(
+                        out.dtype) * out
+            else:
+                out, aux = moe_block(p["ffn"], h2, cfg, ctx, return_aux=True)
         x = x + out
     return ctx.c(x, "batch", "seq", "embed"), new_cache, aux
 
@@ -211,15 +212,16 @@ def _embed_inputs(cfg: ModelConfig, params, tokens=None, embeds=None,
 
 
 def _head(cfg: ModelConfig, params, x, ctx: ShardCtx):
-    x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, w.astype(x.dtype))
-    if cfg.logits_fp32:
-        logits = logits.astype(jnp.float32)
-    if cfg.padded_vocab != cfg.vocab_size:
-        pad_mask = jnp.arange(cfg.padded_vocab) >= cfg.vocab_size
-        logits = jnp.where(pad_mask[None, None, :], -1e30, logits)
-    return ctx.c(logits, "batch", "seq", "vocab")
+    with jax.named_scope("logits"):
+        x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = jnp.einsum("bsd,dv->bsv", x, w.astype(x.dtype))
+        if cfg.logits_fp32:
+            logits = logits.astype(jnp.float32)
+        if cfg.padded_vocab != cfg.vocab_size:
+            pad_mask = jnp.arange(cfg.padded_vocab) >= cfg.vocab_size
+            logits = jnp.where(pad_mask[None, None, :], -1e30, logits)
+        return ctx.c(logits, "batch", "seq", "vocab")
 
 
 def _scan_groups(cfg: ModelConfig, params, x, ctx, *, positions, cache,

@@ -38,6 +38,7 @@ The engine serves two roles:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Dict, List, Optional, Tuple
@@ -85,6 +86,27 @@ def _bucket(n: int, lo: int, hi: int) -> int:
     while b < n and b < hi:
         b *= 2
     return min(b, hi)
+
+
+@contextlib.contextmanager
+def _phase(name: str, record: list):
+    """One host phase of the engine: a profiler span ``engine.<name>``
+    (written only while a trace is recorded) and, on leaving it, the record
+    ``(name, start_ns, end_ns)`` appended to ``record``.  The stamps are
+    ``time.perf_counter_ns()``, the clock of ``step_log``'s ``seconds``,
+    taken inside the span, so one offset per trace maps every record onto
+    its event in the device trace."""
+    with jax.profiler.TraceAnnotation(f"engine.{name}"):
+        start = time.perf_counter_ns()
+        yield
+        record.append((name, start, time.perf_counter_ns()))
+
+
+def _jit(name: str, fn, **kw):
+    """``jax.jit`` under a stable name: the device trace's op paths read
+    ``jit(<name>)/...``."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **kw)
 
 
 def _guard_logits(logits):
@@ -145,7 +167,15 @@ class Engine:
         self._prefill_fns: Dict[Tuple[int, int], callable] = {}
         self._decode_fns: Dict[int, callable] = {}
         self._chunk_fns: Dict[tuple, callable] = {}
-        self.step_log: List[dict] = []    # (kind, batch, seq, seconds[, steps])
+        # one entry per engine call (kind, batch, seconds; seq of a prefill,
+        # steps of a decode chunk), each with the id ``gen`` of the
+        # ``generate`` call it belongs to (None outside one) and its host
+        # phases: records (phase, start_ns, end_ns) on
+        # ``time.perf_counter_ns()``, mirrored by profiler spans
+        # ``engine.<phase>``.  ``generate`` appends its own entry on return.
+        self.step_log: List[dict] = []
+        self._gen: Optional[int] = None   # id of the generate call running
+        self._gens = 0                    # generate calls so far
         self.host_syncs = 0               # device->host blocking round-trips
         self.sample_fallbacks = 0         # non-finite-logit greedy fallbacks
         self.kv_peak = 0                  # max live KV tokens observed
@@ -161,7 +191,7 @@ class Engine:
                 return prefill(cfg, params, tokens, cache=cache,
                                prompt_lens=prompt_lens, ctx=ctx)
 
-            self._prefill_fns[key] = jax.jit(fn, donate_argnums=(1,))
+            self._prefill_fns[key] = _jit("prefill", fn, donate_argnums=(1,))
         return self._prefill_fns[key]
 
     def _get_decode(self, b: int):
@@ -171,7 +201,7 @@ class Engine:
             def fn(params, cache, tokens, kv_lens):
                 return decode_step(cfg, params, cache, tokens, kv_lens, ctx=ctx)
 
-            self._decode_fns[b] = jax.jit(fn, donate_argnums=(1,))
+            self._decode_fns[b] = _jit("decode_step", fn, donate_argnums=(1,))
         return self._decode_fns[b]
 
     def _get_decode_chunk(self, b: int, steps: int, temperature: float = 0.0,
@@ -212,13 +242,15 @@ class Engine:
                     if cfg.decode_unroll_layers:
                         # unrolled decode returns a per-group split dict;
                         # restack so the scan carry keeps one structure
-                        cache = stack_group_cache(cache, cfg.num_groups)
-                    if temperature > 0.0:
-                        keys, subs = _split_slot_keys(keys)
-                        nxt, bad = _sample_tokens(subs, logits, temperature,
-                                                  top_k)
-                    else:
-                        nxt, bad = _guarded_argmax(logits)
+                        with jax.named_scope("kv_cache"):
+                            cache = stack_group_cache(cache, cfg.num_groups)
+                    with jax.named_scope("sample"):
+                        if temperature > 0.0:
+                            keys, subs = _split_slot_keys(keys)
+                            nxt, bad = _sample_tokens(subs, logits,
+                                                      temperature, top_k)
+                        else:
+                            nxt, bad = _guarded_argmax(logits)
                     active = produced < targets
                     produced = produced + active.astype(produced.dtype)
                     step = (jnp.ones_like(kv_lens) if advance_all
@@ -235,7 +267,8 @@ class Engine:
                 return (cache, tok, kv_lens, produced, keys, toks, actives,
                         jnp.sum(nbads))
 
-            self._chunk_fns[key] = jax.jit(fn, donate_argnums=(1,))
+            self._chunk_fns[key] = _jit("decode_chunk", fn,
+                                         donate_argnums=(1,))
         return self._chunk_fns[key]
 
     def new_cache(self, batch_bucket: int):
@@ -245,27 +278,33 @@ class Engine:
     # ------------------------------------------------------------------
     def prefill_batch(self, prompts: List[np.ndarray]):
         """Pad to buckets, run prefill. Returns (cache, kv_lens, last_logits,
-        batch_bucket, wall_seconds)."""
-        b = _bucket(len(prompts), self.ecfg.min_bucket, self.ecfg.max_batch)
-        max_p = max(len(p) for p in prompts)
-        s = min(_bucket(max_p, self.ecfg.prompt_bucket, self.ecfg.max_seq),
-                self.ecfg.max_seq)
-        tokens = np.zeros((b, s), np.int32)
-        lens = np.zeros((b,), np.int32)
-        for i, p in enumerate(prompts):
-            tokens[i, :len(p)] = p[:s]
-            lens[i] = min(len(p), s)
-        lens = np.maximum(lens, 1)
-        cache = self.new_cache(b)
-        fn = self._get_prefill(b, s)
-        t0 = time.perf_counter()
-        last, cache = fn(self.params, cache, jnp.asarray(tokens),
-                         jnp.asarray(lens))
-        last = jax.block_until_ready(last)
-        dt = time.perf_counter() - t0
+        batch_bucket, wall_seconds).  Its phase ``prefill`` covers the
+        padding, the cache allocation, the dispatch and the wait for the
+        logits; ``wall_seconds`` the dispatch and the wait."""
+        phases = []
+        with _phase("prefill", phases):
+            b = _bucket(len(prompts), self.ecfg.min_bucket,
+                        self.ecfg.max_batch)
+            max_p = max(len(p) for p in prompts)
+            s = min(_bucket(max_p, self.ecfg.prompt_bucket,
+                            self.ecfg.max_seq), self.ecfg.max_seq)
+            tokens = np.zeros((b, s), np.int32)
+            lens = np.zeros((b,), np.int32)
+            for i, p in enumerate(prompts):
+                tokens[i, :len(p)] = p[:s]
+                lens[i] = min(len(p), s)
+            lens = np.maximum(lens, 1)
+            cache = self.new_cache(b)
+            fn = self._get_prefill(b, s)
+            t0 = time.perf_counter()
+            last, cache = fn(self.params, cache, jnp.asarray(tokens),
+                             jnp.asarray(lens))
+            last = jax.block_until_ready(last)
+            dt = time.perf_counter() - t0
         self.host_syncs += 1
         self.step_log.append(
-            {"kind": "prefill", "batch": b, "seq": s, "seconds": dt})
+            {"kind": "prefill", "batch": b, "seq": s, "seconds": dt,
+             "gen": self._gen, "phases": phases})
         return cache, jnp.asarray(lens), last, b, dt
 
     def decode_batch(self, cache, kv_lens, tokens):
@@ -302,7 +341,9 @@ class Engine:
         the advancing engine stream (``Engine._sample_key``) — still
         well-distributed randomness per call, but only threading the keys
         gives cross-chunk stream invariance; greedy callers get dummy
-        zeros (never consumed)."""
+        zeros (never consumed).  Its phase ``decode_chunk`` runs from the
+        dispatch to where ``block_until_ready`` returns (``wall_seconds``),
+        then ``readback`` brings the fallback count to the host."""
         b = int(tokens.shape[0])
         if slot_keys is None:
             if temperature > 0.0:
@@ -312,17 +353,20 @@ class Engine:
             else:
                 slot_keys = jnp.zeros((b, 2), jnp.uint32)
         fn = self._get_decode_chunk(b, steps, temperature, top_k)
-        t0 = time.perf_counter()
-        cache, tok, kv_lens, produced, slot_keys, toks, actives, nbad = fn(
-            self.params, cache, tokens, kv_lens, produced, targets,
-            slot_keys)
-        tok = jax.block_until_ready(tok)
-        dt = time.perf_counter() - t0
+        phases = []
+        with _phase("decode_chunk", phases):
+            cache, tok, kv_lens, produced, slot_keys, toks, actives, nbad = \
+                fn(self.params, cache, tokens, kv_lens, produced, targets,
+                   slot_keys)
+            tok = jax.block_until_ready(tok)
+        _, dispatched, ready = phases[0]
+        dt = (ready - dispatched) * 1e-9
         self.host_syncs += 1
-        self.sample_fallbacks += int(nbad)
+        with _phase("readback", phases):
+            self.sample_fallbacks += int(nbad)
         self.step_log.append(
             {"kind": "decode_chunk", "batch": b, "steps": steps,
-             "seq": int(jnp.max(kv_lens)), "seconds": dt})
+             "seconds": dt, "gen": self._gen, "phases": phases})
         return cache, tok, kv_lens, produced, slot_keys, toks, actives, dt
 
     def compact(self, cache, kv_lens, tokens, keep_idx: np.ndarray,
@@ -343,7 +387,8 @@ class Engine:
         keys = None if slot_keys is None else slot_keys[gidx]
         self.host_syncs += 1
         self.step_log.append(
-            {"kind": "compact", "impl": "host", "batch": nb, "syncs": 1})
+            {"kind": "compact", "impl": "host", "batch": nb, "syncs": 1,
+             "gen": self._gen})
         return (cache, kv_lens[gidx], tokens[gidx], nb,
                 int(len(keep_idx)), keys)
 
@@ -365,7 +410,8 @@ class Engine:
         cache, kv_lens, tokens, keys, _ = fused_compact(
             cache, kv_lens, tokens, slot_keys, produced, targets, nb=nb)
         self.step_log.append(
-            {"kind": "compact", "impl": "fused", "batch": nb, "syncs": 0})
+            {"kind": "compact", "impl": "fused", "batch": nb, "syncs": 0,
+             "gen": self._gen})
         return cache, kv_lens, tokens, nb, keys
 
     # ------------------------------------------------------------------
@@ -410,6 +456,31 @@ class Engine:
         with per-request completion times (seconds of engine wall time
         after batch start) and token counts.
         """
+        entry = {"kind": "generate", "gen": self._gens,
+                 "requests": len(prompts), "phases": []}
+        self._gens += 1
+        self._gen = entry["gen"]
+        try:
+            with _phase("generate", entry["phases"]):
+                res = self._generate(entry, prompts, target_tokens, elastic,
+                                     n_max, chunk, return_tokens,
+                                     temperature, top_k, seed)
+        finally:
+            self._gen = None
+        self.step_log.append(entry)
+        return res
+
+    def _generate(self, entry, prompts, target_tokens, elastic, n_max, chunk,
+                  return_tokens, temperature, top_k, seed):
+        """The body of ``generate``.  Besides ``prefill`` and
+        ``decode_chunk`` (their own entries), it records its host phases in
+        ``entry``: ``first_token`` (guard and argmax of the prefill logits,
+        the fallback count, the first tokens to the host), then at every
+        chunk boundary ``compact`` (the decision, and the compaction
+        dispatch), ``upload`` (the slot counters), ``readback`` (actives,
+        produced, tokens and ``kv_lens`` to the host) and ``bookkeeping``
+        (token lists, completion times, ``_track_kv``)."""
+        phases = entry["phases"]
         chunk = int(chunk if chunk is not None else self.ecfg.decode_chunk)
         assert chunk >= 1
         temperature = float(self.ecfg.temperature if temperature is None
@@ -431,27 +502,29 @@ class Engine:
                     "upstream (memory-gated admission) or raise the budget")
         syncs0 = self.host_syncs
         cache, kv_lens, last, b, t_prefill = self.prefill_batch(prompts)
-        self._track_kv(kv_lens, nreq)
-        slot_keys = None
-        if temperature > 0.0:
-            # one key per REQUEST (slot i holds request i right after
-            # prefill); padding slots get keys too, but never emit tokens
-            self._sample_key, base = jax.random.split(self._sample_key)
-            slot_keys = jax.vmap(lambda i: jax.random.fold_in(base, i))(
-                jnp.arange(b))
-            slot_keys, subs = _split_slot_keys(slot_keys)
-            tok, bad0 = _sample_tokens(subs, last, temperature, top_k)
-        else:
-            tok, bad0 = _guarded_argmax(last)
-        self.sample_fallbacks += int(jnp.sum(bad0[:nreq]))
-        live = np.arange(nreq)
-        produced = np.ones(nreq, np.int64)    # first token from prefill
-        done_at = np.full(nreq, np.nan)
-        clock = t_prefill
-        done_at[targets <= 1] = clock
-        out_tokens = ([list(t) for t in
-                       np.asarray(tok)[:nreq, None]] if return_tokens
-                      else None)
+        entry["batch"] = b
+        with _phase("first_token", phases):
+            self._track_kv(kv_lens, nreq)
+            slot_keys = None
+            if temperature > 0.0:
+                # one key per REQUEST (slot i holds request i right after
+                # prefill); padding slots get keys too, but never emit tokens
+                self._sample_key, base = jax.random.split(self._sample_key)
+                slot_keys = jax.vmap(lambda i: jax.random.fold_in(base, i))(
+                    jnp.arange(b))
+                slot_keys, subs = _split_slot_keys(slot_keys)
+                tok, bad0 = _sample_tokens(subs, last, temperature, top_k)
+            else:
+                tok, bad0 = _guarded_argmax(last)
+            self.sample_fallbacks += int(jnp.sum(bad0[:nreq]))
+            live = np.arange(nreq)
+            produced = np.ones(nreq, np.int64)    # first token from prefill
+            done_at = np.full(nreq, np.nan)
+            clock = t_prefill
+            done_at[targets <= 1] = clock
+            out_tokens = ([list(t) for t in
+                           np.asarray(tok)[:nreq, None]] if return_tokens
+                          else None)
 
         def slot_state(bucket, ids):
             prod = np.zeros(bucket, np.int64)
@@ -462,61 +535,71 @@ class Engine:
 
         prod_d = targ_d = None      # device twins of the slot counters
         while True:
-            rem = targets[live] - produced[live]
-            if elastic:
-                still = live[rem > 0]
-                if len(still) == 0:
-                    break
-                if len(still) <= b // 2 and b > self.ecfg.min_bucket:
-                    if self.ecfg.compact_impl == "fused":
-                        # device-resident keep: the produced/targets carry
-                        # from the last chunk (or a fresh upload right
-                        # after prefill) selects the live slots in-jit —
-                        # zero additional host syncs
-                        if prod_d is None:
-                            prod_d, targ_d = slot_state(b, live)
-                        cache, kv_lens, tok, b, slot_keys = \
-                            self.compact_fused(cache, kv_lens, tok, prod_d,
-                                               targ_d, len(still), slot_keys)
-                    else:
-                        # host reference path: map global ids to slot ids
-                        slot_of = {g: i for i, g in enumerate(live)}
-                        keep = np.array([slot_of[g] for g in still], np.int32)
-                        cache, kv_lens, tok, b, _, slot_keys = self.compact(
-                            cache, kv_lens, tok, keep, slot_keys)
-                    live = still
-                    rem = targets[live] - produced[live]
-                    prod_d = targ_d = None   # stale after re-bucketing
-            else:
-                if np.all(produced >= targets):
-                    break
-            # quantize tail chunks to powers of two: produced counts gate
-            # every step, so shorter chunks never change tokens, and this
-            # bounds the executable count at log2(chunk) per bucket
-            rem_max = int(rem.max())
-            steps = chunk if rem_max >= chunk else 1 << (rem_max.bit_length() - 1)
-            prod_d, targ_d = slot_state(b, live)     # also feeds compaction
+            with _phase("compact", phases):
+                rem = targets[live] - produced[live]
+                if elastic:
+                    still = live[rem > 0]
+                    if len(still) == 0:
+                        break
+                    if len(still) <= b // 2 and b > self.ecfg.min_bucket:
+                        if self.ecfg.compact_impl == "fused":
+                            # device-resident keep: the produced/targets
+                            # carry from the last chunk (or a fresh upload
+                            # right after prefill) selects the live slots
+                            # in-jit — zero additional host syncs
+                            if prod_d is None:
+                                prod_d, targ_d = slot_state(b, live)
+                            cache, kv_lens, tok, b, slot_keys = \
+                                self.compact_fused(cache, kv_lens, tok,
+                                                   prod_d, targ_d, len(still),
+                                                   slot_keys)
+                        else:
+                            # host reference path: map global ids to slot ids
+                            slot_of = {g: i for i, g in enumerate(live)}
+                            keep = np.array([slot_of[g] for g in still],
+                                            np.int32)
+                            cache, kv_lens, tok, b, _, slot_keys = \
+                                self.compact(cache, kv_lens, tok, keep,
+                                             slot_keys)
+                        live = still
+                        rem = targets[live] - produced[live]
+                        prod_d = targ_d = None   # stale after re-bucketing
+                else:
+                    if np.all(produced >= targets):
+                        break
+                # quantize tail chunks to powers of two: produced counts gate
+                # every step, so shorter chunks never change tokens, and this
+                # bounds the executable count at log2(chunk) per bucket
+                rem_max = int(rem.max())
+                steps = (chunk if rem_max >= chunk
+                         else 1 << (rem_max.bit_length() - 1))
+            with _phase("upload", phases):
+                prod_d, targ_d = slot_state(b, live)   # also feeds compaction
             cache, tok, kv_lens, prod_d, slot_keys, toks, actives, dt = \
                 self.decode_chunk(cache, kv_lens, tok, prod_d, targ_d, steps,
                                   temperature=temperature, top_k=top_k,
                                   slot_keys=slot_keys)
-            self._track_kv(kv_lens, len(live))
-            clock += dt
-            actives_np = np.asarray(actives)            # [steps, b]
-            produced[live] = np.asarray(prod_d)[:len(live)]
-            if return_tokens:
-                toks_np = np.asarray(toks)
-                for s, g in enumerate(live):
-                    out_tokens[g].extend(
-                        toks_np[actives_np[:, s], s].tolist())
-            newly = live[(produced[live] >= targets[live])
-                         & np.isnan(done_at[live])]
-            slot_of = {g: i for i, g in enumerate(live)}
-            for g in newly:
-                hit = np.nonzero(actives_np[:, slot_of[g]])[0]
-                fin = int(hit[-1]) if hit.size else 0
-                # completion interpolated at that step's chunk fraction
-                done_at[g] = clock - dt + dt * (fin + 1) / steps
+            with _phase("readback", phases):
+                kv_np = np.asarray(kv_lens)
+                actives_np = np.asarray(actives)            # [steps, b]
+                prod_np = np.asarray(prod_d)
+                toks_np = np.asarray(toks) if return_tokens else None
+            with _phase("bookkeeping", phases):
+                self._track_kv(kv_np, len(live))
+                clock += dt
+                produced[live] = prod_np[:len(live)]
+                if return_tokens:
+                    for s, g in enumerate(live):
+                        out_tokens[g].extend(
+                            toks_np[actives_np[:, s], s].tolist())
+                newly = live[(produced[live] >= targets[live])
+                             & np.isnan(done_at[live])]
+                slot_of = {g: i for i, g in enumerate(live)}
+                for g in newly:
+                    hit = np.nonzero(actives_np[:, slot_of[g]])[0]
+                    fin = int(hit[-1]) if hit.size else 0
+                    # completion interpolated at that step's chunk fraction
+                    done_at[g] = clock - dt + dt * (fin + 1) / steps
         done_at[np.isnan(done_at)] = clock
         if not elastic:
             # padded semantics (paper Eq 18): the whole batch is returned
