@@ -72,8 +72,7 @@ class ModelConfig:
     #             all slots share the position; cheapest)
     decode_cache_update: str = "onehot"
     # unroll the (small) decode body over layer groups with per-group cache
-    # leaves: every cache update aliases in place, eliminating the scan's
-    # stacked-cache writeback copies (SPerf gemma decode iteration 3)
+    # leaves (the layer scan updates the stacked cache in place as well)
     decode_unroll_layers: bool = False
     # KV-cache layout: "bshd" (baseline) or "bhsd" (head-major: the decode
     # attention dots read the cache directly, no per-layer transpose copies)

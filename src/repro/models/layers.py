@@ -263,10 +263,14 @@ def decode_attention(q, k_cache, v_cache, kv_lens, *, window: Optional[int],
 
 
 def attention_block(p, x, cfg: ModelConfig, ctx: ShardCtx, *,
-                    positions, cache=None, kv_lens=None, cross_kv=None):
+                    positions, cache=None, layer=None, kv_lens=None,
+                    cross_kv=None):
     """Full attention mixer. Returns (out, new_cache_entry).
 
-    cache: dict(k=[B,Smax,Hkv,D], v=...) or None (full-sequence mode).
+    cache: dict(k=[G,B,Smax,Hkv,D], v=...), the leaves stacked over layer
+    groups, or None (full-sequence mode). This layer writes its rows into
+    index ``layer`` of the stacked leaves and reads that index back, so the
+    leaves can ride the layer scan's carry and update in place.
     """
     is_cross = cross_kv is not None
     q, k, v = _project_qkv(p, x, cfg, ctx, kv_input=cross_kv)
@@ -277,11 +281,11 @@ def attention_block(p, x, cfg: ModelConfig, ctx: ShardCtx, *,
     new_cache = None
     if cache is not None and not is_cross:
         # decode: write this step's k/v at position kv_lens, then attend.
-        k_cache, v_cache = cache["k"], cache["v"]
+        k_all, v_all = cache["k"], cache["v"]
         hm = cfg.cache_layout == "bhsd"      # head-major cache
         cache_ax = (("batch", "kv_heads", "kv_seq", "head_dim") if hm
                     else ("batch", "kv_seq", "kv_heads", "head_dim"))
-        span = k_cache.shape[2] if hm else k_cache.shape[1]
+        span = k_all.shape[3] if hm else k_all.shape[2]
         if x.shape[1] == 1:
             # the cache write, its layout constraints and the attention read
             # are the decode step's KV traffic: one scope for the trace
@@ -294,31 +298,43 @@ def attention_block(p, x, cfg: ModelConfig, ctx: ShardCtx, *,
                 if mode == "uniform":
                     # static-bucket serving: every slot is at the same position
                     pos = slot[0]
-                    start = (0, 0, pos, 0) if hm else (0, pos, 0, 0)
-                    k_cache = lax.dynamic_update_slice(
-                        k_cache, k_new.astype(k_cache.dtype), start)
-                    v_cache = lax.dynamic_update_slice(
-                        v_cache, v_new.astype(v_cache.dtype), start)
+                    start = ((layer, 0, 0, pos, 0) if hm
+                             else (layer, 0, pos, 0, 0))
+                    k_all = lax.dynamic_update_slice(
+                        k_all, k_new[None].astype(k_all.dtype), start)
+                    v_all = lax.dynamic_update_slice(
+                        v_all, v_new[None].astype(v_all.dtype), start)
                 elif mode == "scatter":
                     bidx = jnp.arange(k.shape[0])
                     if hm:
-                        k_cache = k_cache.at[bidx, :, slot].set(
-                            k_new[:, :, 0].astype(k_cache.dtype))
-                        v_cache = v_cache.at[bidx, :, slot].set(
-                            v_new[:, :, 0].astype(v_cache.dtype))
+                        k_all = k_all.at[layer, bidx, :, slot].set(
+                            k_new[:, :, 0].astype(k_all.dtype))
+                        v_all = v_all.at[layer, bidx, :, slot].set(
+                            v_new[:, :, 0].astype(v_all.dtype))
                     else:
-                        k_cache = k_cache.at[bidx, slot].set(
-                            k[:, 0].astype(k_cache.dtype))
-                        v_cache = v_cache.at[bidx, slot].set(
-                            v[:, 0].astype(v_cache.dtype))
-                else:  # onehot (baseline): full-cache read-modify-write
+                        k_all = k_all.at[layer, bidx, slot].set(
+                            k[:, 0].astype(k_all.dtype))
+                        v_all = v_all.at[layer, bidx, slot].set(
+                            v[:, 0].astype(v_all.dtype))
+                else:  # onehot (baseline): read-modify-write of the layer's whole cache
                     oh = (jnp.arange(span)[None, :] ==
-                          slot[:, None]).astype(k_cache.dtype)
+                          slot[:, None]).astype(k_all.dtype)
                     oh = oh[:, None, :, None] if hm else oh[:, :, None, None]
-                    k_cache = k_cache * (1 - oh) + oh * k_new.astype(k_cache.dtype)
-                    v_cache = v_cache * (1 - oh) + oh * v_new.astype(v_cache.dtype)
-                k_cache = ctx.c(k_cache, *cache_ax)
-                v_cache = ctx.c(v_cache, *cache_ax)
+
+                    def rmw(c_all, new):
+                        c = lax.dynamic_index_in_dim(c_all, layer, keepdims=False)
+                        c = c * (1 - oh) + oh * new.astype(c.dtype)
+                        return lax.dynamic_update_index_in_dim(c_all, c, layer, 0)
+
+                    k_all, v_all = rmw(k_all, k_new), rmw(v_all, v_new)
+                k_all = ctx.c(k_all, "layers", *cache_ax)
+                v_all = ctx.c(v_all, "layers", *cache_ax)
+                # read the layer after the write: reading it before would
+                # keep both versions of the stacked leaf alive (a copy)
+                k_cache = ctx.c(lax.dynamic_index_in_dim(
+                    k_all, layer, keepdims=False), *cache_ax)
+                v_cache = ctx.c(lax.dynamic_index_in_dim(
+                    v_all, layer, keepdims=False), *cache_ax)
                 valid = jnp.minimum(kv_lens + 1, span)
                 # ring buffer holds the most recent `valid` tokens; absolute RoPE
                 # was applied before caching so slot order is irrelevant.
@@ -345,11 +361,11 @@ def attention_block(p, x, cfg: ModelConfig, ctx: ShardCtx, *,
             if hm:
                 k_in = k_in.transpose(0, 2, 1, 3)
                 v_in = v_in.transpose(0, 2, 1, 3)
-            k_cache = lax.dynamic_update_slice(
-                k_cache, k_in.astype(k_cache.dtype), (0, 0, 0, 0))
-            v_cache = lax.dynamic_update_slice(
-                v_cache, v_in.astype(v_cache.dtype), (0, 0, 0, 0))
-        new_cache = {"k": k_cache, "v": v_cache}
+            k_all = lax.dynamic_update_slice(
+                k_all, k_in[None].astype(k_all.dtype), (layer, 0, 0, 0, 0))
+            v_all = lax.dynamic_update_slice(
+                v_all, v_in[None].astype(v_all.dtype), (layer, 0, 0, 0, 0))
+        new_cache = {"k": k_all, "v": v_all}
     elif is_cross:
         if "q_norm" in p:
             q = rmsnorm(q, p["q_norm"], cfg.norm_eps)

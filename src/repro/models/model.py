@@ -114,31 +114,32 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
 # ----------------------------------------------------------------------------
 
 def _apply_position(cfg: ModelConfig, mixer: str, ffn: str, p, x, ctx,
-                    *, positions, pos_cache, kv_lens, cross_kv, mode):
-    """One (mixer, ffn) layer. Returns (x, new_pos_cache, aux_loss)."""
+                    *, positions, pos_cache, layer, kv_lens, cross_kv, mode):
+    """One (mixer, ffn) layer. ``pos_cache`` holds the position's cache
+    leaves stacked over groups; the layer reads and writes index ``layer``.
+    Returns (x, new_pos_cache, aux_loss)."""
     aux = jnp.float32(0.0)
     h = L.rmsnorm(x, p["pre_norm"], cfg.norm_eps)
     new_cache = pos_cache
+    at = lambda l: lax.dynamic_index_in_dim(l, layer, keepdims=False)  # noqa: E731
+    put = lambda l, u: lax.dynamic_update_index_in_dim(  # noqa: E731
+        l, u.astype(l.dtype), layer, 0)
 
     if mixer == "attn":
-        attn_cache = None
-        if pos_cache is not None:
-            attn_cache = {"k": pos_cache["k"], "v": pos_cache["v"]}
-        out, upd = L.attention_block(
+        out, new_cache = L.attention_block(
             p["mixer"], h, cfg, ctx, positions=positions,
-            cache=attn_cache, kv_lens=kv_lens)
-        if upd is not None:
-            new_cache = {"k": upd["k"], "v": upd["v"]}
+            cache=pos_cache, layer=layer, kv_lens=kv_lens)
         x = x + out
     elif mixer == "cross_attn":
         if mode == "decode":
             # use cached image K/V
+            k_img, v_img = at(pos_cache["k_img"]), at(pos_cache["v_img"])
             q = jnp.einsum("bsd,dhk->bshk", h, p["mixer"]["wq"].astype(h.dtype))
             if "q_norm" in p["mixer"]:
                 q = L.rmsnorm(q, p["mixer"]["q_norm"], cfg.norm_eps)
             out = L.decode_attention(
-                q, pos_cache["k_img"], pos_cache["v_img"],
-                jnp.full((h.shape[0],), pos_cache["k_img"].shape[1], jnp.int32),
+                q, k_img, v_img,
+                jnp.full((h.shape[0],), k_img.shape[1], jnp.int32),
                 window=None, ctx=ctx)
             out = jnp.einsum("bshk,hkd->bsd", out, p["mixer"]["wo"].astype(h.dtype))
             out = jnp.tanh(p["mixer"]["attn_gate"].astype(jnp.float32)).astype(
@@ -153,13 +154,14 @@ def _apply_position(cfg: ModelConfig, mixer: str, ffn: str, p, x, ctx,
                                p["mixer"]["wv"].astype(h.dtype))
                 if "k_norm" in p["mixer"]:
                     k = L.rmsnorm(k, p["mixer"]["k_norm"], cfg.norm_eps)
-                new_cache = {"k_img": k.astype(pos_cache["k_img"].dtype),
-                             "v_img": v.astype(pos_cache["v_img"].dtype)}
+                new_cache = {"k_img": put(pos_cache["k_img"], k),
+                             "v_img": put(pos_cache["v_img"], v)}
         x = x + out
     elif mixer == "mamba":
-        out, upd = mamba_block(p["mixer"], h, cfg, ctx, state=pos_cache)
+        state = None if pos_cache is None else jax.tree.map(at, pos_cache)
+        out, upd = mamba_block(p["mixer"], h, cfg, ctx, state=state)
         if upd is not None:
-            new_cache = upd
+            new_cache = jax.tree.map(put, pos_cache, upd)
         x = x + out
 
     if ffn != "none":
@@ -177,17 +179,20 @@ def _apply_position(cfg: ModelConfig, mixer: str, ffn: str, p, x, ctx,
 
 
 def _apply_group(cfg: ModelConfig, gparams, x, ctx, *, positions,
-                 group_cache, kv_lens, cross_kv, mode):
+                 cache, layer, kv_lens, cross_kv, mode):
+    """One group of positions. ``cache`` (or None) is the whole stacked
+    cache; each position updates index ``layer`` of its own leaves."""
     auxes = jnp.float32(0.0)
-    new_cache = {} if group_cache is not None else None
+    new_cache = None if cache is None else dict(cache)
     for i, (mixer, ffn) in enumerate(cfg.group_pattern):
         key = f"pos{i}"
-        pos_cache = None if group_cache is None else group_cache.get(key)
+        pos_cache = None if cache is None else cache.get(key)
         x, upd, aux = _apply_position(
             cfg, mixer, ffn, gparams[key], x, ctx, positions=positions,
-            pos_cache=pos_cache, kv_lens=kv_lens, cross_kv=cross_kv, mode=mode)
+            pos_cache=pos_cache, layer=layer, kv_lens=kv_lens,
+            cross_kv=cross_kv, mode=mode)
         auxes = auxes + aux
-        if group_cache is not None and pos_cache is not None:
+        if pos_cache is not None:
             new_cache[key] = upd
     return x, new_cache, auxes
 
@@ -226,21 +231,23 @@ def _head(cfg: ModelConfig, params, x, ctx: ShardCtx):
 
 def _scan_groups(cfg: ModelConfig, params, x, ctx, *, positions, cache,
                  kv_lens, cross_kv, mode):
-    """Scan the group stack; cache (if any) rides along as scan xs/ys."""
+    """Scan the group stack. The cache (if any) rides in the carry and each
+    group updates its own index of the stacked leaves in place; passed as
+    scan xs/ys instead, every call would copy the whole cache."""
 
     def body(carry, xs):
-        h, aux = carry
-        gparams, gcache = xs
-        h, new_cache, a = _apply_group(
-            cfg, gparams, h, ctx, positions=positions, group_cache=gcache,
+        h, aux, cache = carry
+        gparams, g = xs
+        h, cache, a = _apply_group(
+            cfg, gparams, h, ctx, positions=positions, cache=cache, layer=g,
             kv_lens=kv_lens, cross_kv=cross_kv, mode=mode)
-        return (h, aux + a), new_cache
+        return (h, aux + a, cache), None
 
     if cfg.remat:
         body = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable)
 
-    xs = (params["groups"], cache)
-    (x, aux), new_cache = lax.scan(body, (x, jnp.float32(0.0)), xs)
+    xs = (params["groups"], jnp.arange(cfg.num_groups))
+    (x, aux, new_cache), _ = lax.scan(body, (x, jnp.float32(0.0), cache), xs)
     return x, new_cache, aux
 
 
@@ -281,10 +288,9 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, kv_lens,
     Returns (logits [B, vocab], new_cache).
 
     With ``cfg.decode_unroll_layers`` the (small) decode body is unrolled:
-    each group's cache leaves are indexed statically so XLA aliases every
-    cache update in place instead of copying through the scan's stacked
-    carry. ``cache`` may then be either the stacked pytree (sliced here) or
-    a pre-split {"g<i>": group_cache} dict.
+    each group's cache leaves are indexed statically and returned as a
+    pre-split {"g<i>": group_cache} dict. ``cache`` may then be either the
+    stacked pytree (sliced here) or such a dict.
     """
     b = tokens.shape[0]
     positions = kv_lens[:, None]
@@ -297,10 +303,12 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, kv_lens,
             gparams = jax.tree.map(lambda l: l[g], params["groups"])
             gcache = (cache[f"g{g}"] if split
                       else jax.tree.map(lambda l: l[g], cache))
+            # a stack of one group: the layer code updates its index 0
             x, upd, a = _apply_group(
-                cfg, gparams, x, ctx, positions=positions, group_cache=gcache,
+                cfg, gparams, x, ctx, positions=positions,
+                cache=jax.tree.map(lambda l: l[None], gcache), layer=0,
                 kv_lens=kv_lens, cross_kv=None, mode="decode")
-            new_cache[f"g{g}"] = upd
+            new_cache[f"g{g}"] = jax.tree.map(lambda l: l[0], upd)
         logits = _head(cfg, params, x, ctx)
         return logits[:, 0], new_cache
     x, new_cache, _ = _scan_groups(cfg, params, x, ctx, positions=positions,

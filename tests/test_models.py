@@ -189,3 +189,64 @@ def test_sliding_window_cache_ring_buffer():
         kv_lens = kv_lens + 1
         errs.append(float(jnp.abs(sl - full[:, S + t]).max()))
     assert max(errs) < 5e-4, errs
+
+
+# (arch, overrides): every cache-update mode and layout, the ring buffer,
+# SSM state, a hybrid stack and cross-attention image K/V
+_CARRY_CASES = {
+    "scatter": ("qwen2.5-3b", {"decode_cache_update": "scatter"}),
+    "uniform": ("qwen2.5-3b", {"decode_cache_update": "uniform"}),
+    "onehot": ("qwen2.5-3b", {"decode_cache_update": "onehot"}),
+    "bhsd-scatter": ("qwen2.5-3b", {"decode_cache_update": "scatter",
+                                    "cache_layout": "bhsd"}),
+    "bhsd-uniform": ("qwen2.5-3b", {"decode_cache_update": "uniform",
+                                    "cache_layout": "bhsd"}),
+    "bhsd-onehot": ("qwen2.5-3b", {"decode_cache_update": "onehot",
+                                   "cache_layout": "bhsd"}),
+    "ragged-interpret": ("qwen2.5-3b", {"decode_cache_update": "scatter",
+                                        "decode_attention_impl": "ragged"}),
+    "sliding-window": ("mixtral-8x7b", {"decode_cache_update": "scatter",
+                                        "sliding_window": 16}),
+    "mamba2": ("mamba2-2.7b", {}),
+    "jamba": ("jamba-1.5-large-398b", {"decode_cache_update": "scatter"}),
+    "cross-attn": ("llama-3.2-vision-90b", {"decode_cache_update": "scatter"}),
+}
+
+
+@pytest.mark.parametrize("case", list(_CARRY_CASES))
+def test_decode_carry_matches_unrolled(case):
+    """decode_step with the stacked cache in the layer scan's carry equals
+    the unrolled path (per-group leaves, static indices) from one prefilled
+    cache, step after step: logits and every cache leaf of every group."""
+    from repro.models.model import stack_group_cache
+    arch, overrides = _CARRY_CASES[case]
+    cfg = _dropless(get_smoke_config(arch))
+    groups = 3 if len(cfg.group_pattern) == 1 else 2
+    cfg = dataclasses.replace(cfg, num_layers=groups * len(cfg.group_pattern),
+                              **overrides)
+    assert cfg.num_groups == groups
+    unrolled = dataclasses.replace(cfg, decode_unroll_layers=True)
+    params = init_params(param_specs(cfg), RNG, jnp.float32)
+    B, S, STEPS = 2, 12, 6        # 12 + 6 wraps the 16-slot window's ring
+    max_seq = 16 if cfg.sliding_window else 32
+    tokens, kw = _inputs(cfg, B, S + STEPS)
+    lens = (jnp.full((B,), S, jnp.int32)
+            if cfg.decode_cache_update == "uniform"
+            else jnp.array([S, S - 3], jnp.int32))
+    _, cache = prefill(cfg, params, tokens[:, :S], prompt_lens=lens,
+                       cache=init_cache(cfg, B, max_seq, jnp.float32), **kw)
+    step = jax.jit(lambda c, t, n: decode_step(cfg, params, c, t, n))
+    step_u = jax.jit(lambda c, t, n: decode_step(unrolled, params, c, t, n))
+    cache_u, kv_lens = cache, lens
+    for t in range(STEPS):
+        logits, cache = step(cache, tokens[:, S + t], kv_lens)
+        logits_u, split = step_u(cache_u, tokens[:, S + t], kv_lens)
+        cache_u = stack_group_cache(split, cfg.num_groups)
+        # float32 rounding only: the two programs fuse the same ops apart
+        jax.tree.map(lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5),
+            (logits, cache), (logits_u, cache_u))
+        kv_lens = kv_lens + 1
+    # the steps wrote every group's leaves, not one index of the stack
+    k = next(iter(jax.tree.leaves(cache)))
+    assert all(float(jnp.abs(k[g]).max()) > 0 for g in range(cfg.num_groups))
