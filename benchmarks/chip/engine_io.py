@@ -38,22 +38,6 @@ def program_configs(cell: Cell):
     return cfg, serve.engine_config(args, cfg)
 
 
-def check_program_matches(cell: Cell, cfg) -> None:
-    """The program's configuration has to be the published one the file
-    states: the harness builds weights and the reference from the file."""
-    m = cell.shape
-    got = dict(d=cfg.d_model, ffn=cfg.d_ff, layers=cfg.num_layers,
-               heads=cfg.num_heads, kv_heads=cfg.num_kv_heads,
-               head_dim=cfg.head_dim, vocab=cfg.vocab_size, eps=cfg.norm_eps,
-               rope_theta=cfg.rope_theta, tied=cfg.tie_embeddings,
-               qkv_bias=cfg.qkv_bias, dtype=cfg.dtype)
-    want = dataclasses.asdict(m)
-    bad = {k: (got[k], want[k]) for k in want if got[k] != want[k]}
-    if bad:
-        raise SystemExit(f"program config differs from {cell.config_name}: "
-                         f"{bad} (program, file)")
-
-
 def abstract_params(cfg):
     from repro.models.model import param_specs
     from repro.models.params import abstract_params as ap
@@ -64,7 +48,12 @@ def abstract_params(cfg):
 def build_engine(cell: Cell, params):
     from repro.serving.engine import Engine
     cfg, ecfg = program_configs(cell)
-    check_program_matches(cell, cfg)
+    # the harness builds weights and the reference from the file, so the
+    # program's configuration has to be the published one the file states
+    bad = cell.family.check_program(cell.shape, cfg)
+    if bad:
+        raise SystemExit(f"program config differs from {cell.config_name}: "
+                         f"{bad} (program, file)")
     return Engine(cfg, ecfg, params=params)
 
 

@@ -25,7 +25,6 @@ import trace_reduce as tr
 import traffic as tf
 from serve_loop import serve, warm
 from spec import HERE, REPO, Cell
-from weights import make_weights
 
 TRACE_DIR = REPO / ".bench_trace"
 # A traced run profiles the batches dispatched in the last this many
@@ -58,6 +57,10 @@ class RunData:
     @property
     def shape(self):
         return self.cell.shape
+
+    @property
+    def family(self):
+        return self.cell.family
 
     def in_window(self, call) -> bool:
         return self.t0 <= call.t1 < self.t1
@@ -172,8 +175,8 @@ def set_up(cell: Cell, seed: int, eng=None):
     cfg, _ = engine_io.program_configs(cell)
     if eng is not None:
         eng.params = None          # the old weights go before new ones come
-    ref_w, prog_w = make_weights(cell.shape, cell.config, seed,
-                                 cfg.padded_vocab)
+    ref_w, prog_w = cell.family.make_weights(cell.shape, cell.config, seed,
+                                             cfg.padded_vocab)
     if eng is not None:
         eng.params = prog_w
         return eng, ref_w, 0
@@ -248,8 +251,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                        int(refcfg.get("max_rows", 16)))
     length = reference_length(traffic)
     t_ref = clock()
-    cmp = reference.compare(m, ref_w, [(reqs[i].prompt, served[i])
-                                       for i in pick],
+    cmp = reference.compare(cell.family.logits, m, ref_w,
+                            [(reqs[i].prompt, served[i]) for i in pick],
                             length, int(refcfg["block_rows"]),
                             control=control)
     ref_s = clock() - t_ref

@@ -1,11 +1,11 @@
 """Model step, decode: the FLOPs of the live requests' decode steps at
-their real KV lengths, over the chip's peak times the device time inside
-the harness's ``decode_chunk`` spans of the traced window.  The
-compaction gather, dispatched without a wait just before a chunk, runs
-inside that chunk's span; its device time is left out."""
+their real KV lengths (the family's ``decode_flops``), over the chip's
+peak times the device time inside the harness's ``decode_chunk`` spans
+of the traced window.  The compaction gather, dispatched without a wait
+just before a chunk, runs inside that chunk's span; its device time is
+left out."""
 
 import trace_reduce as tr
-import work
 
 # the compaction kernels' events: ``fused_compact.<n>`` and the ops of
 # ``jit(fused_compact)``
@@ -18,7 +18,7 @@ def read(run):
     busy = tr.busy(tr.excluding(run.trace["ops"], COMPACTION))
     flops = dev = 0.0
     for call, (lo, hi) in run.traced_calls("decode_chunk"):
-        flops += work.decode_flops(run.shape, work.chunk_kv_lens(call.work))
+        flops += run.family.decode_flops(run.shape, call.work)
         dev += tr.overlap(busy, lo, hi) * 1e-9
     if dev <= 0:
         return None

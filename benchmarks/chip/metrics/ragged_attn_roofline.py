@@ -3,12 +3,13 @@
 Per kernel call (one layer of one decode step over the bucket) the least
 time is the larger of its FLOPs over the peak and its bytes over the HBM
 bandwidth, counted for the live requests at their valid KV lengths only
-(q, the valid K and V rows, the output).  The share is that least time
+(q, the valid K and V rows, the output) by the family's
+``kernels["ragged_decode_attention"]``, times ``shape.layers``; a family
+without that entry reads nothing.  The share is that least time
 summed over the traced decode chunks, over the device time of the
 kernel's events inside those chunks' spans."""
 
 import trace_reduce as tr
-import work
 
 # The kernel's events in the device trace: its HLO instruction is named
 # after the jitted wrapper, ``ragged_decode_attention.<n>``, with the op
@@ -19,7 +20,8 @@ KERNEL = r"ragged_decode_attention"
 
 
 def read(run):
-    if run.peaks is None:
+    count = run.family.kernels.get(KERNEL)
+    if run.peaks is None or count is None:
         return None
     m = run.shape
     kernel = tr.matching(run.trace["ops"], KERNEL)
@@ -29,7 +31,7 @@ def read(run):
             f = b = 0
             for base, steps in call.work:
                 if j <= steps:
-                    df, db = work.ragged_kernel(m, base + j)
+                    df, db = count(m, base + j)
                     f, b = f + df, b + db
             if f:
                 least += m.layers * max(f / run.peaks["bf16_flops"],

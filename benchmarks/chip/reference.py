@@ -1,23 +1,25 @@
-"""The plain reference: the published decoder's forward pass in float32
-``jax.numpy`` at the highest matmul precision, with no cache, kernel or
-batching of the program's.  It imports nothing of the program.
+"""The plain reference, its generic part: teacher forcing over the
+program's served tokens, in blocks, and the gaps read at every served
+position.  Each family writes only its forward (``families/<family>.py``
+``logits``): the published decoder in float32 ``jax.numpy`` at the
+highest matmul precision, with no cache, kernel or batching of the
+program's, built from the helpers here.  It imports nothing of the
+program.
 
-Given prompts and the tokens the program served, it runs each whole
-sequence once (teacher forcing) and reads, at every served position, the
-gap by which the served token's logit lies below the reference's best.
-A greedy program that computes what the configuration states serves the
+Given prompts and the tokens the program served, ``compare`` runs each
+whole sequence once and reads, at every served position, the gap by
+which the served token's logit lies below the reference's best.  A
+greedy program that computes what the configuration states serves the
 reference's best token up to rounding, so its widest gap is small.
 
 The control puts the next lower precision than the configuration's in
 the program's place (the step a later PR would be tempted by): for a
 bfloat16 model, the same forward with every weight matrix quantized to
 int8 per output channel and the activations in bfloat16; for a float32
-model, weights and activations in bfloat16.  At the same positions it
-reads the gap of the token the control ranks first.
+model, weights and activations in bfloat16 (``_mm``).  At the same
+positions it reads the gap of the token the control ranks first.
 
-Sequences run in blocks of ``block_rows`` rows padded to one length, and
-the layers in a scan that casts one layer's weights to float32 at a
-time, so the float32 copy of the model never exists whole.
+Sequences run in blocks of ``block_rows`` rows padded to one length.
 """
 
 from __future__ import annotations
@@ -27,8 +29,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from spec import ModelShape
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -41,10 +41,10 @@ def _quant_int8(w, axis):
     return (jnp.round(w / jnp.maximum(s, 1e-30)) * s)
 
 
-def _mm(m: ModelShape, x, w, spec, control, in_axes=(0,)):
+def _mm(m, x, w, spec, control, in_axes=(0,)):
     """einsum of activations and a weight: float32 at HIGHEST for the
     reference; for the control, bfloat16 activations and weights one step
-    below the configuration's dtype."""
+    below the configuration's dtype (``m.dtype``)."""
     if control:
         if m.dtype != "float32":
             w = _quant_int8(w, in_axes)
@@ -71,65 +71,8 @@ def _rope(x, pos, theta):
     return x * cos + rot * sin
 
 
-def _layer(m: ModelShape, control: bool, x, w):
-    b, s, d = x.shape
-    pos = jnp.broadcast_to(jnp.arange(s), (b, s))
-    h = _rms(x, w["attn_norm"], m.eps)
-    q = _mm(m, h, w["wq"], "bsd,dhk->bshk", control)
-    k = _mm(m, h, w["wk"], "bsd,dhk->bshk", control)
-    v = _mm(m, h, w["wv"], "bsd,dhk->bshk", control)
-    if m.qkv_bias:
-        q = q + w["bq"].astype(jnp.float32)
-        k = k + w["bk"].astype(jnp.float32)
-        v = v + w["bv"].astype(jnp.float32)
-    q, k = _rope(q, pos, m.rope_theta), _rope(k, pos, m.rope_theta)
-    # query head i reads kv head i // (heads / kv_heads)
-    rep = m.heads // m.kv_heads
-    k = jnp.repeat(k, rep, axis=2)
-    v = jnp.repeat(v, rep, axis=2)
-    if control:
-        q, k, v = (t.astype(jnp.bfloat16) for t in (q, k, v))
-        sc = jnp.einsum("bqhk,bshk->bhqs", q, k,
-                        preferred_element_type=jnp.float32)
-    else:
-        sc = jnp.einsum("bqhk,bshk->bhqs", q, k, precision=HIGHEST)
-    sc = sc / np.sqrt(m.head_dim)
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    sc = jnp.where(causal[None, None], sc, -jnp.inf)
-    p = jax.nn.softmax(sc, axis=-1)
-    if control:
-        o = jnp.einsum("bhqs,bshk->bqhk", p.astype(jnp.bfloat16), v,
-                       preferred_element_type=jnp.float32)
-    else:
-        o = jnp.einsum("bhqs,bshk->bqhk", p, v, precision=HIGHEST)
-    x = x + _mm(m, o, w["wo"], "bshk,hkd->bsd", control, in_axes=(0, 1))
-    h = _rms(x, w["ffn_norm"], m.eps)
-    gate = _mm(m, h, w["w_gate"], "bsd,df->bsf", control)
-    up = _mm(m, h, w["w_up"], "bsd,df->bsf", control)
-    x = x + _mm(m, jax.nn.silu(gate) * up, w["w_down"], "bsf,fd->bsd",
-                control)
-    return x
-
-
-_LAYER_KEYS = ("attn_norm", "wq", "wk", "wv", "wo", "bq", "bk", "bv",
-               "ffn_norm", "w_gate", "w_up", "w_down")
-
-
 @functools.partial(jax.jit, static_argnums=(0, 1))
-def logits(m: ModelShape, control: bool, w, tokens):
-    """[b, s] token ids -> [b, s, vocab] float32 logits."""
-    emb = w["embed"][:m.vocab]
-    x = emb[tokens].astype(jnp.float32)
-    layers = {k: w[k] for k in _LAYER_KEYS if k in w}
-    x, _ = jax.lax.scan(lambda x, lw: (_layer(m, control, x, lw), None),
-                        x, layers)
-    x = _rms(x, w["final_norm"], m.eps)
-    head = emb.T if m.tied else w["lm_head"][:, :m.vocab]
-    return _mm(m, x, head, "bsd,dv->bsv", control)
-
-
-@functools.partial(jax.jit, static_argnums=(0,))
-def _gaps(m: ModelShape, w, tokens, served, alt):
+def _gaps(logits, m, w, tokens, served, alt):
     """Per position: reference best minus the reference's logit of the
     served token, and of the control's first choice ``alt``."""
     lg = logits(m, False, w, tokens)
@@ -138,14 +81,17 @@ def _gaps(m: ModelShape, w, tokens, served, alt):
     return best - at(served), best - at(alt)
 
 
-@functools.partial(jax.jit, static_argnums=(0,))
-def _control_choice(m: ModelShape, w, tokens):
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _control_choice(logits, m, w, tokens):
     return jnp.argmax(logits(m, True, w, tokens), -1).astype(jnp.int32)
 
 
-def compare(m: ModelShape, w, samples, length: int, block_rows: int,
+def compare(logits, m, w, samples, length: int, block_rows: int,
             control: bool = False) -> dict:
-    """``samples``: (prompt ids, served ids) pairs.  Every sequence is
+    """``logits(m, control, w, tokens)``: the family's forward, ``[b, s]``
+    token ids to ``[b, s, vocab]`` float32 logits, jitted with ``m`` and
+    ``control`` static; ``m`` its shape and ``w`` its reference weights.
+    ``samples``: (prompt ids, served ids) pairs.  Every sequence is
     padded to ``length``.  Returns, over all served positions, the widest
     gap of a served token below the reference's best (``program``) and
     the mean gap (``program_mean``); with ``control``, the same of the
@@ -170,11 +116,11 @@ def compare(m: ModelShape, w, samples, length: int, block_rows: int,
             p = len(prompt)
             served[r, p - 1:p - 1 + len(out)] = out
             mask[r, p - 1:p - 1 + len(out)] = True
-        alt = (np.asarray(_control_choice(m, w, toks)) if control
+        alt = (np.asarray(_control_choice(logits, m, w, toks)) if control
                else np.zeros_like(toks))
         gaps = dict(zip(("program", "control"),
                         (np.asarray(g, np.float64) for g in
-                         _gaps(m, w, toks, served, alt))))
+                         _gaps(logits, m, w, toks, served, alt))))
         for k in keys:
             g = gaps[k]
             rows[k] += [float(g[r][mask[r]].max()) for r in range(len(block))]

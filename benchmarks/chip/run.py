@@ -54,7 +54,7 @@ def main(argv=None) -> int:
     from spec import load_cell
     try:
         cell = load_cell(args.workload)
-    except (KeyError, FileNotFoundError) as e:
+    except (KeyError, FileNotFoundError, ValueError) as e:
         print(f"run.py: {e}", file=sys.stderr)
         return 2
 

@@ -36,7 +36,7 @@ import traffic as tf  # noqa: E402
 import work  # noqa: E402
 from engine_io import Call  # noqa: E402
 from harness import RunData, metric_reader  # noqa: E402
-from spec import ModelShape, load_cell  # noqa: E402
+from spec import load_cell  # noqa: E402
 
 DATA = HERE / "data"
 PEAKS = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
@@ -146,17 +146,17 @@ def test_a_reader_that_finds_nothing_returns_none(tiny, trace):
 
 
 def test_work_at_smoke_size(tiny):
-    m = tiny.shape
-    assert m == ModelShape(64, 128, 1, 4, 2, 16, 512, 1e-6, 1e6, True, True,
-                           "float32")
+    fam, m = tiny.family, tiny.shape
+    assert m == fam.ModelShape(64, 128, 1, 4, 2, 16, 512, 1e-6, 1e6, True,
+                               True, "float32")
     # 64*(4+2+2)*16 + 4*16*64 + 3*64*128
-    assert work.matmul_params(m) == 36864
+    assert fam.matmul_params(m) == 36864
     # 2*36864*3 + 2*64*512 + 4*4*16*(3*4/2)
-    assert work.prefill_flops(m, [3]) == 288256
-    # 2*36864 + 2*64*512 + 4*4*16*5
-    assert work.decode_flops(m, [5]) == 140544
+    assert fam.prefill_flops(m, [3]) == 288256
+    # one step from KV length 4: 2*36864 + 2*64*512 + 4*4*16*5
+    assert fam.decode_flops(m, [(4, 1)]) == 140544
     # flops 4*4*16*5; bytes q+out 2*4*16*2, K+V 2*5*2*16*2
-    assert work.ragged_kernel(m, 5) == (1280, 896)
+    assert fam.kernels["ragged_decode_attention"](m, 5) == (1280, 896)
     assert list(work.chunk_kv_lens([(9, 2), (4, 1)])) == [10, 11, 5]
 
 
