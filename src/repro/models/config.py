@@ -86,11 +86,11 @@ class ModelConfig:
     #   dense  - padded softmax over the full cache span (baseline,
     #            always selectable)
     #   ragged - repro.kernels ragged decode kernel: per-request early exit
-    #            over KV blocks, so early-finished slots stop paying padded
-    #            KV compute. bshd layout only (bhsd keeps the dense path).
-    #            block_kv is the largest power of two (<=128) dividing the
-    #            cache span — non-power-of-two spans degrade toward
-    #            block_kv=1, so keep max_seq a power of two.
+    #            over KV blocks, so a slot fetches and computes only the
+    #            blocks that hold its valid rows. bshd layout only (bhsd
+    #            keeps the dense path). block_kv is a power of two dividing
+    #            the cache span (``block_kv_for``) — non-power-of-two spans
+    #            degrade toward block_kv=1, so keep max_seq a power of two.
     decode_attention_impl: str = "auto"
 
     # vlm

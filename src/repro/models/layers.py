@@ -102,15 +102,6 @@ def _project_qkv(p, x, cfg: ModelConfig, ctx: ShardCtx, kv_input=None):
     return q, k, v
 
 
-def _ragged_block_kv(span: int, cap: int = 128) -> int:
-    """Largest power-of-two KV block <= cap that tiles the cache span (the
-    ragged decode kernel requires span % block_kv == 0)."""
-    b = 1
-    while b * 2 <= min(span, cap) and span % (b * 2) == 0:
-        b *= 2
-    return b
-
-
 def _group_query(q, num_kv_heads: int):
     """[B,S,Hq,D] -> [B,S,Hkv,G,D] grouping query heads per KV head."""
     b, s, hq, d = q.shape
@@ -340,13 +331,12 @@ def attention_block(p, x, cfg: ModelConfig, ctx: ShardCtx, *,
                 # was applied before caching so slot order is irrelevant.
                 if cfg.resolved_decode_attention_impl == "ragged" and not hm:
                     # per-request early exit over KV blocks (elastic batching at
-                    # the kernel level): a short request only pays its own span;
-                    # interpret mode resolves via kernels.default_interpret
+                    # the kernel level): a short request fetches only its own
+                    # blocks; interpret mode resolves via kernels.default_interpret
                     from repro.kernels.ragged_decode_attention.ops import (
                         ragged_decode_attention)
                     out = ragged_decode_attention(
-                        q[:, 0], k_cache, v_cache, valid,
-                        block_kv=_ragged_block_kv(span))[:, None]
+                        q[:, 0], k_cache, v_cache, valid)[:, None]
                 else:
                     out = decode_attention(q, k_cache, v_cache, valid,
                                            window=None, ctx=ctx,

@@ -50,8 +50,11 @@ from jax import lax
 
 from repro.distributed.sharding import ShardCtx, NULL_CTX
 from repro.models.config import ModelConfig
+from repro.kernels.ragged_decode_attention.kernel import (
+    block_kv_for, kv_blocks)
 from repro.models.model import (
-    param_specs, init_cache, prefill, decode_step, stack_group_cache)
+    param_specs, cache_specs, init_cache, prefill, decode_step,
+    stack_group_cache)
 from repro.models.params import init_params
 
 
@@ -148,6 +151,25 @@ def _sample_tokens(keys, logits, temperature: float, top_k: Optional[int]):
     return jnp.where(bad, greedy, sampled), bad
 
 
+def _kv_read_geometry(cfg: ModelConfig, ecfg: EngineConfig):
+    """(span, block_kv, KV heads) of decode attention's reads of one
+    layer's cache, or None where the model keeps no KV cache.  The ragged
+    kernel reads blocks of ``block_kv_for``'s size; the dense path reads
+    the whole span as one block."""
+    head_major = cfg.cache_layout == "bhsd"
+    spans = [leaf["k"].shape[3 if head_major else 2] for leaf in cache_specs(
+        cfg, 1, ecfg.max_seq).values() if "k" in leaf]
+    if not spans:
+        return None
+    span, hkv = spans[0], cfg.num_kv_heads
+    ragged = (cfg.resolved_decode_attention_impl == "ragged"
+              and not head_major)
+    block = (block_kv_for(span, hkv * cfg.head_dim
+                          * jnp.dtype(ecfg.cache_dtype).itemsize)
+             if ragged else span)
+    return span, block, hkv
+
+
 def _split_slot_keys(keys):
     """Advance every slot's key one step: returns (carried, subkeys)."""
     split = jax.vmap(jax.random.split)(keys)
@@ -179,6 +201,7 @@ class Engine:
         self.host_syncs = 0               # device->host blocking round-trips
         self.sample_fallbacks = 0         # non-finite-logit greedy fallbacks
         self.kv_peak = 0                  # max live KV tokens observed
+        self._kv_read = _kv_read_geometry(cfg, ecfg)
         self._sample_key = jax.random.PRNGKey(seed)   # decode sampling stream
 
     # ------------------------------------------------------------------
@@ -220,9 +243,10 @@ class Engine:
         ``compact``).  ``temperature=0`` (the default) is greedy argmax
         and never touches the keys.
 
-        Emits the per-step sampled token and active mask so the caller can
-        reconstruct exact token streams / completion steps after the single
-        end-of-chunk sync. ``kv_lens`` advances only for slots still below
+        Emits the per-step sampled token, active mask and KV lengths so the
+        caller can reconstruct exact token streams / completion steps (and
+        count the KV blocks each step read) after the single end-of-chunk
+        sync. ``kv_lens`` advances only for slots still below
         their target (except in 'uniform' cache-update mode, which requires
         lock-step positions), so early-exited slots stop moving their ring
         pointer; with the ragged decode-attention kernel they also stop
@@ -237,6 +261,7 @@ class Engine:
             def fn(params, cache, tok, kv_lens, produced, targets, keys):
                 def body(carry, _):
                     cache, tok, kv_lens, produced, keys = carry
+                    step_lens = kv_lens
                     logits, cache = decode_step(cfg, params, cache, tok,
                                                 kv_lens, ctx=ctx)
                     if cfg.decode_unroll_layers:
@@ -258,14 +283,14 @@ class Engine:
                     kv_lens = jnp.minimum(kv_lens + step, max_seq - 1)
                     nbad = jnp.sum((bad & active).astype(jnp.int32))
                     return (cache, nxt, kv_lens, produced, keys), \
-                        (nxt, active, nbad)
+                        (nxt, active, nbad, step_lens)
 
-                carry, (toks, actives, nbads) = lax.scan(
+                carry, (toks, actives, nbads, step_lens) = lax.scan(
                     body, (cache, tok, kv_lens, produced, keys), None,
                     length=steps)
                 cache, tok, kv_lens, produced, keys = carry
                 return (cache, tok, kv_lens, produced, keys, toks, actives,
-                        jnp.sum(nbads))
+                        jnp.sum(nbads), step_lens)
 
             self._chunk_fns[key] = _jit("decode_chunk", fn,
                                          donate_argnums=(1,))
@@ -343,7 +368,9 @@ class Engine:
         gives cross-chunk stream invariance; greedy callers get dummy
         zeros (never consumed).  Its phase ``decode_chunk`` runs from the
         dispatch to where ``block_until_ready`` returns (``wall_seconds``),
-        then ``readback`` brings the fallback count to the host."""
+        then ``readback`` brings the fallback count and each step's KV
+        lengths to the host; its ``step_log`` entry counts ``kv_blocks``
+        (:meth:`_kv_blocks`) from those lengths."""
         b = int(tokens.shape[0])
         if slot_keys is None:
             if temperature > 0.0:
@@ -355,18 +382,20 @@ class Engine:
         fn = self._get_decode_chunk(b, steps, temperature, top_k)
         phases = []
         with _phase("decode_chunk", phases):
-            cache, tok, kv_lens, produced, slot_keys, toks, actives, nbad = \
-                fn(self.params, cache, tokens, kv_lens, produced, targets,
-                   slot_keys)
+            (cache, tok, kv_lens, produced, slot_keys, toks, actives, nbad,
+             step_lens) = fn(self.params, cache, tokens, kv_lens, produced,
+                             targets, slot_keys)
             tok = jax.block_until_ready(tok)
         _, dispatched, ready = phases[0]
         dt = (ready - dispatched) * 1e-9
         self.host_syncs += 1
         with _phase("readback", phases):
             self.sample_fallbacks += int(nbad)
+            blocks = self._kv_blocks(np.asarray(step_lens))
         self.step_log.append(
             {"kind": "decode_chunk", "batch": b, "steps": steps,
-             "seconds": dt, "gen": self._gen, "phases": phases})
+             "seconds": dt, "gen": self._gen, "phases": phases,
+             "kv_blocks": blocks})
         return cache, tok, kv_lens, produced, slot_keys, toks, actives, dt
 
     def compact(self, cache, kv_lens, tokens, keep_idx: np.ndarray,
@@ -413,6 +442,19 @@ class Engine:
             {"kind": "compact", "impl": "fused", "batch": nb, "syncs": 0,
              "gen": self._gen})
         return cache, kv_lens, tokens, nb, keys
+
+    def _kv_blocks(self, step_lens: np.ndarray) -> List[int]:
+        """[fetched, reserved] KV blocks of one decode chunk, summed over
+        its steps, slots and KV heads (one layer's worth): at each step a
+        slot attends to ``min(kv_len + 1, span)`` rows, of which the kernel
+        fetches :func:`fetched_blocks` blocks out of its reservation's
+        ``span / block_kv``."""
+        if self._kv_read is None:
+            return [0, 0]
+        span, block, hkv = self._kv_read
+        fetched, reserved = kv_blocks(np.minimum(step_lens + 1, span),
+                                      span, block)
+        return [fetched * hkv, reserved * hkv]
 
     # ------------------------------------------------------------------
     def _track_kv(self, kv_lens, nlive: int) -> int:

@@ -187,3 +187,28 @@ def test_the_decode_chunk_program_carries_stable_names(engine):
     kernel = [p for p in paths if "jit(ragged_decode_attention)" in p]
     assert kernel and all("/kv_cache/" in p for p in kernel)
     assert any(p.endswith("kv_cache/scatter") for p in paths)
+
+
+def test_decode_chunk_counts_the_kv_blocks_it_fetches(engine):
+    """``kv_blocks`` = [fetched, reserved] over the chunk's steps, slots
+    and KV heads, counted by hand.  Span 128 in blocks of 64, two KV
+    heads; slot 1 finishes after one step and slot 3 owes nothing, so
+    neither advances, and each step a slot attends to kv_len + 1 rows."""
+    cfg = dataclasses.replace(engine.cfg, decode_attention_impl="ragged")
+    eng = Engine(cfg, ECFG, params=engine.params)
+    assert eng._kv_read == (128, 64, 2)
+    b = 4
+    i32 = lambda *v: jnp.array(v, jnp.int32)  # noqa: E731
+    eng.decode_chunk(eng.new_cache(b), i32(0, 30, 63, 100), i32(1, 1, 1, 1),
+                     i32(0, 0, 0, 0), i32(3, 1, 3, 0), steps=2)
+    # step 0 attends to 1, 31, 64, 101 rows: 1 + 1 + 1 + 2 blocks;
+    # step 1 to 2, 32, 65, 101: 1 + 1 + 2 + 2
+    fetched = (5 + 6) * 2
+    reserved = 2 * b * 2 * 2
+    assert eng.step_log[-1]["kv_blocks"] == [fetched, reserved]
+    # the dense path reads each slot's whole span as one block
+    dense = Engine(engine.cfg, ECFG, params=engine.params)
+    dense.decode_chunk(dense.new_cache(b), i32(0, 30, 63, 100),
+                       i32(1, 1, 1, 1), i32(0, 0, 0, 0), i32(3, 1, 3, 0),
+                       steps=2)
+    assert dense.step_log[-1]["kv_blocks"] == [2 * b * 2, 2 * b * 2]

@@ -47,6 +47,23 @@ def test_ragged_decode_kernel_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("b,s,hkv", [
+    (4, 8192, 8),     # internlm2-1.8b's cell: 4 slots of 8k context
+    (16, 1024, 2),    # qwen2.5-3b's cells: 16 slots of 1k
+])
+def test_ragged_decode_kernel_compiles_at_cell_shapes(one_chip, b, s, hkv):
+    """At its own block size, whose K and V buffers fit the VMEM the chip
+    scopes for a kernel."""
+    from repro.kernels.ragged_decode_attention import ragged_decode_attention
+    args = (_sds((b, 16, D), jnp.bfloat16, one_chip),
+            _sds((b, s, hkv, D), jnp.bfloat16, one_chip),
+            _sds((b, s, hkv, D), jnp.bfloat16, one_chip),
+            _sds((b,), jnp.int32, one_chip))
+    compiled = jax.jit(lambda q, k, v, n: ragged_decode_attention(
+        q, k, v, n, interpret=False)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_compaction_gather_compiles(one_chip):
     """One stacked KV-cache leaf [layers, B, S, Hkv, D] plus the per-slot
     vectors (kv_lens, tokens, PRNG keys) through the gather kernel."""
